@@ -5,7 +5,7 @@ Two pieces every trace-only analyzer needs:
 
   * ``Walker`` — forward dataflow over a (closed) jaxpr on the union
     semilattice of frozensets. The base class owns the structural
-    recursion — ``pjit``, ``scan`` (carry fixpoint), ``while``
+    recursion — ``jit``, ``scan`` (carry fixpoint), ``while``
     (fixpoint), ``cond`` (branch join), ``shard_map`` (with region-
     depth tracking), single-sub-jaxpr generic calls, and a
     conservative everything-flows-everywhere fallback — so a pass only
@@ -139,7 +139,7 @@ class Walker:
             return
 
         name = eqn.primitive.name
-        if name == "pjit":
+        if name == "jit":
             outs = self.run(eqn.params["jaxpr"], in_t)
             for ov, t in zip(eqn.outvars, outs):
                 write(env, ov, t)

@@ -16,7 +16,7 @@ step (``analysis._jaxpr.trace_step``):
 
   * every ``psum``'s axes name real mesh axes of the region, are
     manual (not auto) axes, and use no ``axis_index_groups``;
-  * outputs whose ``out_names`` shard a dimension over a data axis are
+  * outputs whose ``out_specs`` shard a dimension over a data axis are
     per-example: no data-axis psum may appear in their lineage;
   * outputs replicated over the data axes carry exactly one data-axis
     psum, covering ALL the data axes (a partial reduction leaves the
@@ -27,9 +27,9 @@ step (``analysis._jaxpr.trace_step``):
 losses additionally need a psum over the *model* axes (each tensor
 shard holds only part of every example's norm) and gradients reduce
 over data and model both. The executable pipeline still rejects big
-model axes (jax 0.4.x shard_map limitation, ROADMAP), but the static
-half of the DP×TP contract is pinned here and checked degenerate-form
-against today's 1-D traces.
+model axes (it replicates parameters into every shard, ROADMAP R1), but
+the static half of the DP×TP contract is pinned here and checked
+degenerate-form against today's 1-D traces.
 """
 from __future__ import annotations
 
@@ -65,8 +65,8 @@ def expected_schedule(plan: plan_mod.Plan, mesh,
     psum'd once over the data axes. With a real model axis (DP×TP) the
     per-example entries gain a model-axis psum — each tensor shard
     holds only its slice of every example's norm and loss — and the
-    gradient reduces over both; that form is what the jax-upgrade
-    DP×TP pipeline must emit to pass this pass unchanged.
+    gradient reduces over both; that form is what a DP×TP pipeline
+    must emit to pass this pass unchanged.
     """
     data = tuple(data_axes)
     model = tuple(a for a in mesh.axis_names
@@ -156,12 +156,19 @@ class _PsumWalker(_J.Walker):
         return [t | {tok} for t in in_t]
 
 
+def _spec_axes(dim) -> frozenset:
+    """Mesh axes one PartitionSpec entry shards its dimension over."""
+    if dim is None:
+        return frozenset()
+    return frozenset((dim,) if isinstance(dim, str) else dim)
+
+
 def _analyze_region(eqn, data_axes: Tuple[str, ...], index: int,
                     findings: List[Finding]) -> RegionReport:
     mesh = eqn.params["mesh"]
-    auto = frozenset(eqn.params.get("auto", frozenset()))
-    manual = tuple(a for a in mesh.axis_names if a not in auto)
-    out_names = eqn.params["out_names"]
+    manual_axes = eqn.params["manual_axes"]
+    manual = tuple(a for a in mesh.axis_names if a in manual_axes)
+    auto = frozenset(mesh.axis_names) - frozenset(manual)
     body = eqn.params["jaxpr"]
 
     walker = _PsumWalker()
@@ -192,8 +199,8 @@ def _analyze_region(eqn, data_axes: Tuple[str, ...], index: int,
 
     outputs = []
     data = frozenset(data_axes)
-    for pos, (names, taint) in enumerate(zip(out_names, out_t)):
-        sharded = any(data & set(ax) for ax in names.values())
+    for pos, (spec, taint) in enumerate(zip(eqn.params["out_specs"], out_t)):
+        sharded = any(data & _spec_axes(dim) for dim in spec)
         dpsums = [sites[t] for t in taint
                   if t in sites and data & set(sites[t].axes)]
         outputs.append(RegionOutput(pos, sharded, len(dpsums)))

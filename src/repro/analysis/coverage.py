@@ -13,7 +13,7 @@ parameter leaf by taint analysis.
 **Taint propagation.** Each jaxpr variable carries the set of parameter
 leaves it (transitively) depends on. Ordinary equations union their
 operands' taint into their outputs — the structural recursion into
-``pjit``, ``scan``, ``while``/``cond``, and foreign ``custom_vjp``
+``jit``, ``scan``, ``while``/``cond``, and foreign ``custom_vjp``
 calls lives in the shared front end (``analysis._jaxpr.Walker``). A
 pex equation (identified by its registered backward rule —
 ``taps.PEX_OPS``) is the one place taint is *blocked*: the weight-slot

@@ -213,7 +213,7 @@ def dce(closed):
 # the walker
 # ---------------------------------------------------------------------------
 
-_STRUCTURAL = frozenset({"pjit", "shard_map", "scan", "while", "cond"})
+_STRUCTURAL = frozenset({"jit", "shard_map", "scan", "while", "cond"})
 
 
 @dataclasses.dataclass
@@ -465,7 +465,7 @@ def _phase(rec: Rec, batch_size: int, stat_elems: int,
 def _needed_by(records: Sequence[Rec], top_outvars, out_labels):
     """Which output field(s) each record feeds: reverse reachability
     over the record list, crossing container boundaries by seeding each
-    body's outvars from the call site's outvars (1:1 for pjit /
+    body's outvars from the call site's outvars (1:1 for jit /
     shard_map / scan / while / cond). Iterated to fixpoint — one
     reverse sweep resolves one nesting level."""
     var_groups: Dict[int, frozenset] = {}

@@ -18,13 +18,13 @@ whole consumer plans (DESIGN.md §9): the fused norms→weights→
 reweighted-backward core runs per shard inside one region, with only
 the gradient psum crossing devices.
 
-Mesh axes not named in ``data_axes`` (e.g. "model") are left in auto
-mode, so the standard ``("data", "model")`` mesh from ``launch.mesh``
-composes directly. On the pinned jax (0.4.x) XLA's manual-subgroup
-SPMD support is incomplete: auto axes of extent > 1 crash the
-partitioner, so those are rejected with an actionable error until the
-toolchain moves — pure data parallelism (any extent, any number of
-data axes) is fully supported.
+Parameters are replicated into every shard, so mesh axes not named in
+``data_axes`` (e.g. "model") must have extent 1: the standard
+``("data", "model")`` mesh from ``launch.mesh`` composes directly when
+its model axis is a singleton. A model axis of extent > 1 is rejected
+with an actionable error until the per-example pipeline shards
+parameters — pure data parallelism (any extent, any number of data
+axes) is fully supported.
 """
 from __future__ import annotations
 
@@ -32,7 +32,6 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import passes as api
@@ -61,6 +60,18 @@ def _reject_aux(aux) -> None:
             "into loss_vec/sq_norms or run single-device (mesh=None)")
 
 
+def _check_data_only(mesh: Mesh, data_axes: DataAxes) -> None:
+    big_rest = [a for a in mesh.axis_names
+                if a not in data_axes and mesh.shape[a] > 1]
+    if big_rest:
+        raise NotImplementedError(
+            f"mesh axes {big_rest} (extent > 1) outside data_axes="
+            f"{data_axes}: the per-example pipeline replicates parameters "
+            f"into every shard and does not yet combine per-example norms "
+            f"across a model axis; run it data-parallel-only, or include "
+            f"the axis in data_axes")
+
+
 def _wrap(mesh: Mesh, data_axes: DataAxes, fn: Callable, n_out_sharded: int,
           n_out_replicated: int) -> Callable:
     """shard_map ``fn(params, batch) -> (*sharded, *replicated)``.
@@ -71,17 +82,9 @@ def _wrap(mesh: Mesh, data_axes: DataAxes, fn: Callable, n_out_sharded: int,
     so in-model tensor-parallel constraints keep working.
     """
     dp = P(data_axes)
-    rest = frozenset(mesh.axis_names) - frozenset(data_axes)
-    big_rest = [a for a in rest if mesh.shape[a] > 1]
-    if big_rest:
-        raise NotImplementedError(
-            f"mesh axes {big_rest} (extent > 1) outside data_axes="
-            f"{data_axes}: jax 0.4.x shard_map auto-subgroups crash "
-            f"XLA's SPMD partitioner; run per-example sharding "
-            f"data-parallel-only, or include the axis in data_axes")
+    _check_data_only(mesh, data_axes)
     # extent-1 non-data axes are safely manual (replication over a
-    # singleton is trivial), so no `auto=` — which 0.4.x also lacks
-    # outside jit.
+    # singleton is trivial)
     out_specs = tuple([dp] * n_out_sharded + [P()] * n_out_replicated)
 
     def body(params, batch):
@@ -92,8 +95,8 @@ def _wrap(mesh: Mesh, data_axes: DataAxes, fn: Callable, n_out_sharded: int,
         with shd.use_rules(None, {}):
             return fn(params, batch)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(), dp),
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(), dp),
+                         out_specs=out_specs, check_vma=False)
 
 
 def value_and_norms(loss_fn: Callable, params, batch, spec: PexSpec,
@@ -210,14 +213,7 @@ def plan_step(plan, acc_loss: Callable, params, batch, batch_size: int, *,
                                 layout, loss_weights=loss_weights)
     data_axes = _norm_axes(data_axes)
     dp = P(data_axes)
-    rest = frozenset(mesh.axis_names) - frozenset(data_axes)
-    big_rest = [a for a in rest if mesh.shape[a] > 1]
-    if big_rest:
-        raise NotImplementedError(
-            f"mesh axes {big_rest} (extent > 1) outside data_axes="
-            f"{data_axes}: jax 0.4.x shard_map auto-subgroups crash "
-            f"XLA's SPMD partitioner; run per-example sharding "
-            f"data-parallel-only, or include the axis in data_axes")
+    _check_data_only(mesh, data_axes)
 
     def fused_fn(sub, b, bs, lw):
         local_b = shd.local_batch(bs, data_axes, mesh)
@@ -254,8 +250,8 @@ def plan_step(plan, acc_loss: Callable, params, batch, batch_size: int, *,
         out_specs = tuple([dp] * n_sharded + [P()] * int(has_grads))
         in_specs = (P(), dp) + ((dp,) if lw is not None else ())
         args = (params, b) + ((lw,) if lw is not None else ())
-        res = list(shard_map(run, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)(*args))
+        res = list(jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)(*args))
 
         lv = res.pop(0)
         sq = res.pop(0) if has_sq else None

@@ -17,9 +17,9 @@ batch sharding — run it on any box:
     PYTHONPATH=src python -m repro.dist.selfcheck
     PYTHONPATH=src python -m repro.dist.selfcheck --arch llama3.2-1b --batch 16
 
-``--model-parallel N`` exists to demonstrate the pinned-jax limit: any
-N > 1 exits with the dist.pex NotImplementedError (shard_map
-auto-subgroups crash XLA's SPMD partitioner on jax 0.4.x).
+``--model-parallel N`` shows the pipeline's data-parallel-only limit:
+any N > 1 exits with the dist.pex NotImplementedError (parameters are
+replicated into every shard; ROADMAP R1).
 """
 
 import argparse

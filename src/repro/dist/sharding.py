@@ -128,14 +128,10 @@ def sharding_tree(axes_tree: Any, mesh: Optional[Mesh] = None) -> Any:
 # --- mesh construction -----------------------------------------------------
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` across jax versions: newer jax wants explicit
-    Auto axis types for GSPMD-partitioned meshes; 0.4.x has neither the
-    kwarg nor ``jax.sharding.AxisType``."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axis types: the rules-based
+    placements here are GSPMD-partitioned, not explicitly sharded."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_over(devices: Sequence, shape: Sequence[int],

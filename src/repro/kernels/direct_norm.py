@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gram_norm import OUT_LANES
+
 
 def flop_estimate(b: int, s: int, p_in: int, p_out: int) -> int:
     """MXU+fold flops for padded shapes: the HᵀZ̄ contraction
@@ -69,7 +71,7 @@ def launch_contract(b: int, s: int, p_in: int, p_out: int, *,
         blocks=(
             Block("h", (1, tile_s, chunk_in), dtype),
             Block("zbar", (1, tile_s, chunk_out), dtype),
-            Block("out", (1, 1), jnp.float32, kind="out"),
+            Block("out", (1, 1, OUT_LANES), jnp.float32, kind="out"),
             Block("g_acc", (chunk_in, chunk_out), jnp.float32,
                   kind="scratch", accumulator=True),
         ),
@@ -103,11 +105,11 @@ def _kernel(n_s: int, h_ref, z_ref, out_ref, g_acc):
 
         @pl.when(jnp.logical_and(ci == 0, co == 0))
         def _set():
-            out_ref[0, 0] = partial
+            out_ref[...] = jnp.full(out_ref.shape, partial, jnp.float32)
 
         @pl.when(jnp.logical_or(ci != 0, co != 0))
         def _add():
-            out_ref[0, 0] += partial
+            out_ref[...] += partial
 
 
 @functools.partial(jax.jit, static_argnames=("tile_s", "chunk_in",
@@ -147,9 +149,11 @@ def direct_norm(h: jax.Array, zbar: jax.Array, *, tile_s: int = 128,
             pl.BlockSpec((1, tile_s, chunk_out),
                          lambda bi, ci, co, si: (bi, si, co)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bi, ci, co, si: (bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
+        # lane-dense per-example row (see gram_norm.OUT_LANES)
+        out_specs=pl.BlockSpec((1, 1, OUT_LANES),
+                               lambda bi, ci, co, si: (bi, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, OUT_LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((chunk_in, chunk_out), jnp.float32)],
         cost_estimate=cost,
         interpret=interpret,
-    )(h, zbar)[:, 0]
+    )(h, zbar)[:, 0, 0]

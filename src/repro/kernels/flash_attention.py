@@ -77,7 +77,7 @@ def _kernel(scale: float, softcap: Optional[float], window: Optional[int],
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def launch_contracts(b: int, hq: int, hkv: int, sq: int, sk: int, d: int, *,
@@ -108,7 +108,7 @@ def launch_contracts(b: int, hq: int, hkv: int, sq: int, sk: int, d: int, *,
             Block("k", (1, 1, block_k, d), dtype),
             Block("v", (1, 1, block_k, d), dtype),
             Block("o", (1, 1, block_q, d), dtype, kind="out"),
-            Block("lse", (1, 1, block_q), jnp.float32, kind="out"),
+            Block("lse", (1, 1, block_q, 1), jnp.float32, kind="out"),
             Block("acc", (block_q, d), jnp.float32, kind="scratch",
                   accumulator=True),
             Block("m", (block_q, 1), jnp.float32, kind="scratch",
@@ -127,8 +127,8 @@ def launch_contracts(b: int, hq: int, hkv: int, sq: int, sk: int, d: int, *,
             Block("k", (1, 1, block_k, d), dtype),
             Block("v", (1, 1, block_k, d), dtype),
             Block("do", (1, 1, block_q, d), dtype),
-            Block("lse", (1, 1, block_q), jnp.float32),
-            Block("delta", (1, 1, block_q), jnp.float32),
+            Block("lse", (1, 1, block_q, 1), jnp.float32),
+            Block("delta", (1, 1, block_q, 1), jnp.float32),
             Block("dq", (1, 1, block_q, d), dtype, kind="out"),
             Block("dq_acc", (block_q, d), jnp.float32, kind="scratch",
                   accumulator=True),
@@ -144,8 +144,8 @@ def launch_contracts(b: int, hq: int, hkv: int, sq: int, sk: int, d: int, *,
             Block("k", (1, 1, block_k, d), dtype),
             Block("v", (1, 1, block_k, d), dtype),
             Block("do", (1, 1, block_q, d), dtype),
-            Block("lse", (1, 1, block_q), jnp.float32),
-            Block("delta", (1, 1, block_q), jnp.float32),
+            Block("lse", (1, 1, block_q, 1), jnp.float32),
+            Block("delta", (1, 1, block_q, 1), jnp.float32),
             Block("dk", (1, 1, block_k, d), dtype, kind="out"),
             Block("dv", (1, 1, block_k, d), dtype, kind="out"),
             Block("dk_acc", (block_k, d), jnp.float32, kind="scratch",
@@ -168,7 +168,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_k: int = 256, interpret: bool = False,
                     return_lse: bool = False):
     """q (B,Hq,Sq,D), k/v (B,Hkv,Sk,D), Hq % Hkv == 0 → (B,Hq,Sq,D)
-    [+ log-sum-exp (B,Hq,Sq) when return_lse, for the backward kernels].
+    [+ log-sum-exp (B,Hq,Sq,1) when return_lse, for the backward kernels;
+    the unit minor axis keeps its (block_q, 1) block tile-legal].
 
     Causal; caller pads Sq/Sk to block multiples (padded k rows land
     above the diagonal or in masked tail — exact)."""
@@ -194,12 +195,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, h, qi, kj: (bi, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, h, qi, kj: (bi, h, qi)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda bi, h, qi, kj: (bi, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -256,12 +257,12 @@ def _dq_kernel(scale, softcap, window, bq, bk, n_k,
     def _compute():
         s, mask, chain = _bwd_scores(q_ref, k_ref, scale, softcap, window,
                                      qi, kj, bq, bk)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        p = jnp.exp(s - lse_ref[0, 0])
         do = do_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0, 0][:, None])
+        ds = p * (dp - dvec_ref[0, 0])
         if chain is not None:
             ds = ds * chain
         ds = jnp.where(mask, ds, 0.0)
@@ -291,7 +292,7 @@ def _dkv_kernel(scale, softcap, window, bq, bk, n_q, rep,
     def _compute():
         s, mask, chain = _bwd_scores(q_ref, k_ref, scale, softcap, window,
                                      qi, kj, bq, bk)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        p = jnp.exp(s - lse_ref[0, 0])
         do = do_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         q = q_ref[0, 0].astype(jnp.float32)
@@ -300,7 +301,7 @@ def _dkv_kernel(scale, softcap, window, bq, bk, n_q, rep,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dvec_ref[0, 0][:, None])
+        ds = p * (dp - dvec_ref[0, 0])
         if chain is not None:
             ds = ds * chain
         ds = jnp.where(mask, ds, 0.0)
@@ -328,11 +329,12 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale,
     hkv, sk = k.shape[1], k.shape[2]
     rep = hq // hkv
     n_q, n_k = sq // block_q, sk // block_k
-    dvec = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    dvec = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                   keepdims=True)
 
     q_map = lambda bi, h, qi, kj: (bi, h, qi, 0)
     k_map = lambda bi, h, qi, kj: (bi, h // rep, kj, 0)
-    lse_map = lambda bi, h, qi, kj: (bi, h, qi)
+    lse_map = lambda bi, h, qi, kj: (bi, h, qi, 0)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale, softcap, window, block_q,
@@ -343,8 +345,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale,
             pl.BlockSpec((1, 1, block_k, d), k_map),
             pl.BlockSpec((1, 1, block_k, d), k_map),
             pl.BlockSpec((1, 1, block_q, d), q_map),
-            pl.BlockSpec((1, 1, block_q), lse_map),
-            pl.BlockSpec((1, 1, block_q), lse_map),
+            pl.BlockSpec((1, 1, block_q, 1), lse_map),
+            pl.BlockSpec((1, 1, block_q, 1), lse_map),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), q_map),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -357,7 +359,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale,
     # contiguous; the block is emitted once per (b, hkv, kj)
     qk_map = lambda bi, hk, kj, r, qi: (bi, hk * rep + r, qi, 0)
     kk_map = lambda bi, hk, kj, r, qi: (bi, hk, kj, 0)
-    lsek_map = lambda bi, hk, kj, r, qi: (bi, hk * rep + r, qi)
+    lsek_map = lambda bi, hk, kj, r, qi: (bi, hk * rep + r, qi, 0)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale, softcap, window, block_q,
                           block_k, n_q, rep),
@@ -367,8 +369,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale,
             pl.BlockSpec((1, 1, block_k, d), kk_map),
             pl.BlockSpec((1, 1, block_k, d), kk_map),
             pl.BlockSpec((1, 1, block_q, d), qk_map),
-            pl.BlockSpec((1, 1, block_q), lsek_map),
-            pl.BlockSpec((1, 1, block_q), lsek_map),
+            pl.BlockSpec((1, 1, block_q, 1), lsek_map),
+            pl.BlockSpec((1, 1, block_q, 1), lsek_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), kk_map),

@@ -52,6 +52,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: lane width of the per-example output row (one TPU vreg lane row)
+OUT_LANES = 128
+
 
 def flop_estimate(b: int, s: int, p_in: int, p_out: int, *,
                   tile_s: int = 128, triangular: bool = True) -> int:
@@ -96,7 +99,7 @@ def launch_contract(b: int, s: int, p_in: int, p_out: int, *,
             Block("h_j", (1, tile_s, chunk_in), dtype),
             Block("z_i", (1, tile_s, chunk_out), dtype),
             Block("z_j", (1, tile_s, chunk_out), dtype),
-            Block("out", (1, 1), jnp.float32, kind="out"),
+            Block("out", (1, 1, OUT_LANES), jnp.float32, kind="out"),
             Block("a_acc", (tile_s, tile_s), jnp.float32, kind="scratch",
                   accumulator=True),
             Block("b_acc", (tile_s, tile_s), jnp.float32, kind="scratch",
@@ -156,11 +159,11 @@ def _kernel_tri(k_in: int, k_out: int, n_k: int, ti_ref, tj_ref,
 
         @pl.when(t == 0)
         def _set():
-            out_ref[0, 0] = partial
+            out_ref[...] = jnp.full(out_ref.shape, partial, jnp.float32)
 
         @pl.when(t != 0)
         def _add():
-            out_ref[0, 0] += partial
+            out_ref[...] += partial
 
 
 def _kernel_full(k_in: int, k_out: int, n_k: int,
@@ -192,11 +195,11 @@ def _kernel_full(k_in: int, k_out: int, n_k: int,
 
         @pl.when(jnp.logical_and(i == 0, j == 0))
         def _set():
-            out_ref[0, 0] = partial
+            out_ref[...] = jnp.full(out_ref.shape, partial, jnp.float32)
 
         @pl.when(jnp.logical_or(i != 0, j != 0))
         def _add():
-            out_ref[0, 0] += partial
+            out_ref[...] += partial
 
 
 @functools.partial(jax.jit, static_argnames=("tile_s", "chunk_in",
@@ -236,7 +239,9 @@ def gram_norm(h: jax.Array, zbar: jax.Array, *, tile_s: int = 128,
         pltpu.VMEM((tile_s, tile_s), jnp.float32),
         pltpu.VMEM((tile_s, tile_s), jnp.float32),
     ]
-    out_shape = jax.ShapeDtypeStruct((b, 1), jnp.float32)
+    # one lane-dense (1, 128) row per example, every lane holding the
+    # example's sum: a (1, 1) block breaks Mosaic's (8, 128) tiling rule
+    out_shape = jax.ShapeDtypeStruct((b, 1, OUT_LANES), jnp.float32)
 
     if not triangular:
         def h_map(bi, i, j, k):
@@ -260,12 +265,13 @@ def gram_norm(h: jax.Array, zbar: jax.Array, *, tile_s: int = 128,
                 pl.BlockSpec((1, tile_s, chunk_out), z_map),
                 pl.BlockSpec((1, tile_s, chunk_out), z_map_j),
             ],
-            out_specs=pl.BlockSpec((1, 1), lambda bi, i, j, k: (bi, 0)),
+            out_specs=pl.BlockSpec((1, 1, OUT_LANES),
+                                   lambda bi, i, j, k: (bi, 0, 0)),
             out_shape=out_shape,
             scratch_shapes=scratch,
             cost_estimate=cost,
             interpret=interpret,
-        )(h, h, zbar, zbar)[:, 0]
+        )(h, h, zbar, zbar)[:, 0, 0]
 
     ti, tj = _tri_maps(n_s)
     n_tri = ti.shape[0]
@@ -291,7 +297,8 @@ def gram_norm(h: jax.Array, zbar: jax.Array, *, tile_s: int = 128,
             pl.BlockSpec((1, tile_s, chunk_out), z_map_i),
             pl.BlockSpec((1, tile_s, chunk_out), z_map_j),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bi, t, k, ti_r, tj_r: (bi, 0)),
+        out_specs=pl.BlockSpec((1, 1, OUT_LANES),
+                               lambda bi, t, k, ti_r, tj_r: (bi, 0, 0)),
         scratch_shapes=scratch,
     )
     return pl.pallas_call(
@@ -300,4 +307,4 @@ def gram_norm(h: jax.Array, zbar: jax.Array, *, tile_s: int = 128,
         out_shape=out_shape,
         cost_estimate=cost,
         interpret=interpret,
-    )(jnp.asarray(ti), jnp.asarray(tj), h, h, zbar, zbar)[:, 0]
+    )(jnp.asarray(ti), jnp.asarray(tj), h, h, zbar, zbar)[:, 0, 0]

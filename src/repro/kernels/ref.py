@@ -22,6 +22,20 @@ def gram_norm_ref(h: jax.Array, zbar: jax.Array) -> jax.Array:
     return jnp.sum(hh * zz, axis=(1, 2))
 
 
+def segmented_norm_ref(h: jax.Array, zbar: jax.Array, seg_ids: jax.Array,
+                       n_seg: int) -> jax.Array:
+    """Per-segment ||Σ_{t:seg=j} h_t z̄_tᵀ||_F² by the masked gram
+    identity; rows with ``seg_ids >= n_seg`` are dropped.
+
+    h: (T, p_in), zbar: (T, p_out), seg_ids: (T,) int → (n_seg,) f32.
+    """
+    hh = jnp.einsum("si,ti->st", h, h, preferred_element_type=_F32)
+    zz = jnp.einsum("si,ti->st", zbar, zbar, preferred_element_type=_F32)
+    same = seg_ids[:, None] == seg_ids[None, :]
+    rows = jnp.sum(jnp.where(same, hh * zz, 0.0), axis=1)
+    return jax.ops.segment_sum(rows, seg_ids, num_segments=n_seg)
+
+
 def rowsumsq_ref(x: jax.Array) -> jax.Array:
     """(B, N) → (B,) Σ x² in f32."""
     return jnp.sum(jnp.square(x.astype(_F32)), axis=-1)

@@ -32,14 +32,14 @@ The grid is ``(k_in, k_out, n_work)`` with the work axis innermost: for
 one (C_in, C_out) feature block the whole sorted sequence streams once,
 the per-segment partial gradient living only as a (C_in × C_out) f32
 VMEM scratch that is reset at ``first`` and squared at ``last``. The
-fold lands in a per-(ci, co) output **column** of shape (1, n_seg) via
+fold lands in a per-(ci, co) output **row** of shape (1, 1, n_seg) via
 a one-hot lane select (no dynamic VMEM store — Mosaic-safe); the
-wrapper reduces the (k_in·k_out, n_seg) partials. Nothing of size
+wrapper reduces the (k_in·k_out, 1, n_seg) partials. Nothing of size
 ``(B, p_in, p_out)`` ever exists in HBM or VMEM.
 
 VMEM budget at Tt=128, C_in=C_out=512, f32 inputs:
     2 panels · 128·512·4 B = 512 KiB + scratch 512·512·4 B = 1 MiB
-    + out column n_seg·4 B
+    + out row n_seg·4 B
 well under the ~16 MiB/core budget; MXU dims are 128-aligned.
 
 Consecutive work items in the same token block reuse the resident
@@ -83,7 +83,7 @@ def bytes_estimate(n_work: int, tile_t: int, p_in: int, p_out: int,
     """HBM traffic: each work item streams one H panel per p_out block
     column and one Z̄ panel per p_in block row (panels resident across
     same-block items are still charged — the estimate is a ceiling),
-    plus the (k_in·k_out, n_seg) partial outputs."""
+    plus the (k_in·k_out, 1, n_seg) partial outputs."""
     n_ci = p_in // chunk_in
     n_co = p_out // chunk_out
     panels = n_work * tile_t * (chunk_in * n_ci * n_co + chunk_out * n_ci * n_co)
@@ -103,7 +103,7 @@ def launch_contract(t: int, p_in: int, p_out: int, n_seg_pad: int,
         blocks=(
             Block("h", (tile_t, chunk_in), dtype),
             Block("zbar", (tile_t, chunk_out), dtype),
-            Block("out", (1, n_seg_pad), jnp.float32, kind="out",
+            Block("out", (1, 1, n_seg_pad), jnp.float32, kind="out",
                   accumulator=True),
             Block("g_acc", (chunk_in, chunk_out), jnp.float32,
                   kind="scratch", accumulator=True),
@@ -145,7 +145,7 @@ def _kernel(blk_ref, r0_ref, r1_ref, seg_ref, first_ref, last_ref,
     def _fold():
         partial = jnp.sum(jnp.square(g_acc[...]))
         # one-hot lane select instead of a dynamic VMEM store
-        lanes = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 2)
         out_ref[...] += jnp.where(lanes == seg_ref[w], partial, 0.0)
 
 
@@ -189,7 +189,7 @@ def segmented_norm_sorted(blk: jax.Array, r0: jax.Array, r1: jax.Array,
         return (blk_r[w], co)
 
     def out_map(ci, co, w, blk_r, r0_r, r1_r, seg_r, first_r, last_r):
-        return (ci * k_out + co, 0)
+        return (ci * k_out + co, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -198,15 +198,17 @@ def segmented_norm_sorted(blk: jax.Array, r0: jax.Array, r1: jax.Array,
             pl.BlockSpec((tile_t, chunk_in), h_map),
             pl.BlockSpec((tile_t, chunk_out), z_map),
         ],
-        out_specs=pl.BlockSpec((1, n_seg_pad), out_map),
+        # a (1, n_seg_pad) block of a 2-D array breaks Mosaic's (8, 128)
+        # tiling rule; the unit middle axis equals its array dim
+        out_specs=pl.BlockSpec((1, 1, n_seg_pad), out_map),
         scratch_shapes=[pltpu.VMEM((chunk_in, chunk_out), jnp.float32)],
     )
     partials = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((k_in * k_out, n_seg_pad),
+        out_shape=jax.ShapeDtypeStruct((k_in * k_out, 1, n_seg_pad),
                                        jnp.float32),
         cost_estimate=cost,
         interpret=interpret,
     )(blk, r0, r1, seg, first, last, h, zbar)
-    return jnp.sum(partials, axis=0)
+    return jnp.sum(partials, axis=(0, 1))

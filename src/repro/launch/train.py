@@ -15,6 +15,7 @@ import jax
 
 from repro.core.taps import PexSpec
 from repro.data.pipeline import DataConfig
+from repro.launch.cache import use_compile_cache
 from repro.models import registry
 from repro.nn.param import count_params, unbox
 from repro.optim import adamw
@@ -43,6 +44,7 @@ def main():
                     help="shard the batch over all local devices via the "
                          "dist.pex shard_map pipeline")
     args = ap.parse_args()
+    use_compile_cache()
 
     aspec = registry.get(args.arch)
     cfg = aspec.smoke() if args.smoke else aspec.full()

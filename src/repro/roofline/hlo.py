@@ -103,9 +103,6 @@ def collective_counts(hlo_text: str) -> Dict[str, int]:
 
 def compiled_cost(compiled) -> Tuple[float, float]:
     """(flops, bytes accessed) from a compiled executable's
-    cost_analysis, tolerating the jax-0.4.x list-of-one-dict form and
-    absent analyses (returns zeros)."""
+    cost_analysis; an absent analysis gives zeros."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))

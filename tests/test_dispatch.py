@@ -131,8 +131,6 @@ def test_cost_analysis_exposes_flops():
 
     for fn in (run_tri, run_dir):
         ca = jax.jit(fn).lower(h, z).compile().cost_analysis()
-        if isinstance(ca, list):  # jax<0.4.30 returned [dict]
-            ca = ca[0]
         assert ca.get("flops", 0) > 0
 
     full = gn.flop_estimate(1, 1024, 512, 512, triangular=False)

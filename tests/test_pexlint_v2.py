@@ -144,11 +144,13 @@ def test_collectives_region_shape():
     assert len(rep.regions) == 1
     r = rep.regions[0]
     assert r.mesh_axes == ("data",)
-    assert len(r.psums) == 1
-    assert r.psums[0].axes == ("data",)
     per_ex = [o for o in r.outputs if o.sharded_over_data]
     repl = [o for o in r.outputs if not o.sharded_over_data]
     assert per_ex and repl
+    # jax binds one psum per gradient leaf: exactly one per replicated
+    # output, each over the data axis
+    assert len(r.psums) == len(repl)
+    assert all(ps.axes == ("data",) for ps in r.psums)
     assert all(o.data_psums == 0 for o in per_ex)
     assert all(o.data_psums == 1 for o in repl)
 
