@@ -61,6 +61,15 @@ from repro.core.passes import (add_grad_noise, add_grad_noise_segmented,
                                check_noise_args, clip_coefficients)
 from repro.core.provenance import mark_grad_tree, mark_seed
 
+#: ``jax.named_scope`` names of the step's phases. They reach the
+#: compiled HLO's ``op_name`` metadata, and a profiler trace's
+#: reduction charges each device operation to the innermost one it
+#: lies in; they change nothing else in the program.
+FORWARD = "pex.forward"
+NORMS_BACKWARD = "pex.norms_backward"
+WEIGHT_BACKWARD = "pex.weight_backward"
+NOISE = "pex.noise"
+
 
 # ---------------------------------------------------------------------------
 # consumers — the declarative surface
@@ -356,7 +365,8 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
     """
     if not plan.needs_norms and not plan.needs_grads:
         # plain forward: the tap is never live, the program is the model
-        lv, _, _, aux = acc_loss(params, None, batch)
+        with jax.named_scope(FORWARD):
+            lv, _, _, aux = acc_loss(params, None, batch)
         return lv, aux, None, None, None, None, None
 
     if not plan.needs_norms:
@@ -365,16 +375,19 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
             lv, _tok, _acc, aux = acc_loss(p, None, batch)
             return lv, aux
 
-        lv, vjp_fn, aux = jax.vjp(f, params, has_aux=True)
+        with jax.named_scope(FORWARD):
+            lv, vjp_fn, aux = jax.vjp(f, params, has_aux=True)
         seed = mark_seed(loss_weights.astype(lv.dtype), kind="weighted") \
             if loss_weights is not None \
             else mark_seed(jnp.ones_like(lv), kind="plain")
-        (grads,) = vjp_fn(seed)
+        with jax.named_scope(WEIGHT_BACKWARD):
+            (grads,) = vjp_fn(seed)
         return (lv, aux, None, grads, loss_weights, None, None)
 
     acc0 = layout.init(batch_size)
-    lv, tok, aux, vjp_fn = _vjp(acc_loss, params, batch, acc0,
-                                plan.token_weighted)
+    with jax.named_scope(FORWARD):
+        lv, tok, aux, vjp_fn = _vjp(acc_loss, params, batch, acc0,
+                                    plan.token_weighted)
     if plan.token_weighted and tok.shape[:2] != (lv.shape[0], layout.seq):
         raise ValueError(
             f"the registered per-token loss map has shape {tok.shape}, "
@@ -392,22 +405,26 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
     grads = None
     if plan.needs_grads and not plan.weighted and loss_weights is None:
         # norms and gradients fold into ONE backward (paper §4/§5)
-        grads, sq = vjp_fn(*seeds(mark_seed(ones, kind="plain")))
+        with jax.named_scope(WEIGHT_BACKWARD):
+            grads, sq = vjp_fn(*seeds(mark_seed(ones, kind="plain")))
     else:
         # dW chains dead → DCE
-        _, sq = vjp_fn(*seeds(mark_seed(ones, kind="norms")))
+        with jax.named_scope(NORMS_BACKWARD):
+            _, sq = vjp_fn(*seeds(mark_seed(ones, kind="norms")))
 
-    w, tw, cc = _compose_weights(plan, sq, loss_weights)
+    with jax.named_scope(NORMS_BACKWARD):
+        w, tw, cc = _compose_weights(plan, sq, loss_weights)
     if plan.needs_grads and grads is None:
-        if tw is not None:
-            tok_seed = tw if w is None else tw * w[:, None]
-            grads, _ = vjp_fn((jnp.zeros_like(lv),
-                               mark_seed(tok_seed.astype(tok.dtype),
-                                         kind="weighted")))
-        else:
-            seed = mark_seed(ones, kind="plain") if w is None \
-                else mark_seed(w.astype(lv.dtype), kind="weighted")
-            grads, _ = vjp_fn(*seeds(seed))
+        with jax.named_scope(WEIGHT_BACKWARD):
+            if tw is not None:
+                tok_seed = tw if w is None else tw * w[:, None]
+                grads, _ = vjp_fn((jnp.zeros_like(lv),
+                                   mark_seed(tok_seed.astype(tok.dtype),
+                                             kind="weighted")))
+            else:
+                seed = mark_seed(ones, kind="plain") if w is None \
+                    else mark_seed(w.astype(lv.dtype), kind="weighted")
+                grads, _ = vjp_fn(*seeds(seed))
     return lv, aux, sq, grads, w, tw, cc
 
 
@@ -497,13 +514,14 @@ def execute(plan: Plan, acc_loss: Callable, params, batch,
     if plan.noise is not None and grads is not None:
         scale = plan.noise.scale if plan.noise.scale is not None \
             else plan.clip.clip_norm
-        if plan.noise.segments is not None:
-            grads = add_grad_noise_segmented(grads, plan.noise.noise_std,
-                                             scale, plan.noise.rng,
-                                             plan.noise.segments)
-        else:
-            grads = add_grad_noise(grads, plan.noise.noise_std, scale,
-                                   plan.noise.rng)
+        with jax.named_scope(NOISE):
+            if plan.noise.segments is not None:
+                grads = add_grad_noise_segmented(
+                    grads, plan.noise.noise_std, scale, plan.noise.rng,
+                    plan.noise.segments)
+            else:
+                grads = add_grad_noise(grads, plan.noise.noise_std, scale,
+                                       plan.noise.rng)
     return StepResult(jnp.sum(lv), lv, aux, sq, grads, w, tw, cc, gns,
                       samp, sub_sq)
 

@@ -59,6 +59,10 @@ import numpy as np
 from repro.core import norms as N
 from repro.dist.sharding import shard as _shard
 
+#: ``jax.named_scope`` around each backward rule's per-example stat
+#: (``layout.add_*``), for the trace reduction's phase split
+STATS = "pex.stats"
+
 _ACC_DTYPE = jnp.float32
 
 
@@ -403,7 +407,8 @@ def _pex_dense_bwd(method, use_pallas, group, layout, res, cts):
     zbar, acc_bar = cts
     dh = jnp.einsum("...o,io->...i", zbar, w).astype(h.dtype)
     dw = jnp.einsum("...i,...o->io", h, zbar).astype(w.dtype)
-    dacc = layout.add_dense(acc_bar, h, zbar, group, method, use_pallas)
+    with jax.named_scope(STATS):
+        dacc = layout.add_dense(acc_bar, h, zbar, group, method, use_pallas)
     return dh, dw, dacc
 
 
@@ -432,8 +437,9 @@ def _pex_dense_batched_bwd(method, use_pallas, group, layout, res, cts):
     zbar, acc_bar = cts
     dh = jnp.einsum("b...o,bio->b...i", zbar, w).astype(h.dtype)
     dw = jnp.einsum("b...i,b...o->bio", h, zbar).astype(w.dtype)
-    dacc = layout.add_dense_batched(acc_bar, h, zbar, group, method,
-                                    use_pallas)
+    with jax.named_scope(STATS):
+        dacc = layout.add_dense_batched(acc_bar, h, zbar, group, method,
+                                        use_pallas)
     return dh, dw, dacc
 
 
@@ -466,8 +472,9 @@ def _pex_dense_expert_bwd(group, n_examples, method, use_pallas, layout,
     zbar, acc_bar = cts
     dx = jnp.einsum("ecf,edf->ecd", zbar, w).astype(x.dtype)
     dw = jnp.einsum("ecd,ecf->edf", x, zbar).astype(w.dtype)
-    dacc = layout.add_expert(acc_bar, x, zbar, seg, tok, group, n_examples,
-                             method, use_pallas)
+    with jax.named_scope(STATS):
+        dacc = layout.add_expert(acc_bar, x, zbar, seg, tok, group, n_examples,
+                                 method, use_pallas)
     return (dx, dw, _int_zero_cotangent(seg), _int_zero_cotangent(tok),
             dacc)
 
@@ -501,8 +508,9 @@ def _pex_dense_expert_grouped_bwd(group, bg, method, use_pallas, layout,
     zbar, acc_bar = cts
     dx = jnp.einsum("gecf,edf->gecd", zbar, w).astype(x.dtype)
     dw = jnp.einsum("gecd,gecf->edf", x, zbar).astype(w.dtype)
-    dacc = layout.add_expert_grouped(acc_bar, x, zbar, seg, tok, group, bg,
-                                     method, use_pallas)
+    with jax.named_scope(STATS):
+        dacc = layout.add_expert_grouped(acc_bar, x, zbar, seg, tok, group, bg,
+                                         method, use_pallas)
     return (dx, dw, _int_zero_cotangent(seg), _int_zero_cotangent(tok),
             dacc)
 
@@ -529,7 +537,8 @@ def _pex_bias_bwd(group, layout, _, cts):
     zbar, acc_bar = cts
     reduce_axes = tuple(range(zbar.ndim - 1))
     db = jnp.sum(zbar, axis=reduce_axes).astype(zbar.dtype)
-    dacc = layout.add_bias(acc_bar, zbar, group)
+    with jax.named_scope(STATS):
+        dacc = layout.add_bias(acc_bar, zbar, group)
     return zbar, db, dacc
 
 
@@ -556,7 +565,8 @@ def _pex_scale_bwd(group, layout, res, cts):
     dh = (zbar * g).astype(h.dtype)
     reduce_axes = tuple(range(zbar.ndim - 1))
     dg = jnp.sum(zbar * h, axis=reduce_axes).astype(g.dtype)
-    dacc = layout.add_scale(acc_bar, h, zbar, group)
+    with jax.named_scope(STATS):
+        dacc = layout.add_scale(acc_bar, h, zbar, group)
     return dh, dg, dacc
 
 
@@ -586,7 +596,8 @@ def _pex_embed_bwd(group, layout, res, cts):
     flat_ids = ids.reshape(-1)
     flat_z = zbar.reshape(-1, zbar.shape[-1])
     dtable = jnp.zeros_like(table).at[flat_ids].add(flat_z.astype(table.dtype))
-    dacc = layout.add_embedding(acc_bar, ids, zbar, group)
+    with jax.named_scope(STATS):
+        dacc = layout.add_embedding(acc_bar, ids, zbar, group)
     return dtable, _int_zero_cotangent(ids), dacc
 
 

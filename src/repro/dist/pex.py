@@ -185,7 +185,9 @@ def clipped_value_and_grads(loss_fn: Callable, params, batch, spec: PexSpec,
     loss_vec, sq, loss, grads = _wrap(mesh, data_axes, run, 2, 2)(
         params, batch)
     if noise_std > 0.0:
-        grads = api.add_grad_noise(grads, noise_std, clip_norm, noise_rng)
+        with jax.named_scope(plan_mod.NOISE):
+            grads = api.add_grad_noise(grads, noise_std, clip_norm,
+                                       noise_rng)
     return PexResult(loss, loss_vec, {}, sq, grads)
 
 

@@ -37,7 +37,17 @@ def global_norm(tree) -> jax.Array:
                         for x in jax.tree_util.tree_leaves(tree)))
 
 
+#: ``jax.named_scope`` of the global clip and the apply, for the trace
+#: reduction's phase split
+SCOPE = "optim.adamw"
+
+
 def update(cfg: AdamWConfig, state: AdamWState, params, grads):
+    with jax.named_scope(SCOPE):
+        return _update(cfg, state, params, grads)
+
+
+def _update(cfg: AdamWConfig, state: AdamWState, params, grads):
     step = state.step + 1
     if cfg.global_clip is not None:
         gn = global_norm(grads)

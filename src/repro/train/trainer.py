@@ -31,6 +31,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.core import plan as plan_mod
@@ -200,41 +201,56 @@ class Trainer:
         return jax.tree_util.tree_map(sub, batch)
 
     # ------------------------------------------------------------------
+    def lower_step(self, batch) -> jax.stages.Lowered:
+        """The step program that ``run_step`` runs on ``batch``, lowered
+        and not run (``.compile().as_text()`` is the HLO the device
+        runs). The trainer's state is left as it is."""
+        return self._step_fn.lower(self.params, self.opt_state, self.err,
+                                   batch, self.rng)
+
     def run_step(self, batch) -> Dict:
+        """One step. The host's parts are ``jax.profiler``
+        annotations (``trainer.*``), so that a profiler trace can put
+        the device's idle time down to them; ``time_s`` is the whole
+        step on the host clock."""
         t0 = time.perf_counter()
-        self.rng, sub = jax.random.split(self.rng)
-        params, opt_state, err, res = self._step_fn(
-            self.params, self.opt_state, self.err, batch, sub)
-        loss = float(res.loss)
+        with TraceAnnotation("trainer.dispatch"):
+            self.rng, sub = jax.random.split(self.rng)
+            params, opt_state, err, res = self._step_fn(
+                self.params, self.opt_state, self.err, batch, sub)
+        with TraceAnnotation("trainer.fetch_loss"):
+            loss = float(res.loss)
         bad = not np.isfinite(loss)
         if not bad and res.sq_norms is not None:
-            bad = not bool(jnp.all(jnp.isfinite(res.sq_norms)))
+            with TraceAnnotation("trainer.finite_check"):
+                bad = not bool(jnp.all(jnp.isfinite(res.sq_norms)))
         quarantined = 0
         if bad:
-            # non-finite loss/grad: the per-example norms (and losses)
-            # the pass already computed identify the poisoned examples;
-            # reweight them out through the plan's loss_weights= path
-            # and retry — skip examples, not steps (DESIGN.md §11)
-            mask = self._quarantine_mask(res)
-            if mask is None:
-                self.events.append({"step": self.step, "kind": "skip_step",
-                                    "reason": "every example non-finite"})
-                dt = time.perf_counter() - t0
-                m = {"step": self.step, "loss": loss, "time_s": dt,
-                     "skipped": 1}
-                self.metrics.append(m)
-                return m          # previous params/opt_state kept
-            quarantined = int((~mask).sum())
-            self.events.append({
-                "step": self.step, "kind": "quarantine",
-                "examples": [int(i) for i in np.flatnonzero(~mask)]})
-            if self._step_fn_weighted is None:
-                self._step_fn_weighted = self._build_step(weighted=True)
-            params, opt_state, err, res = self._step_fn_weighted(
-                self.params, self.opt_state, self.err,
-                self._substitute_rows(batch, mask), sub,
-                jnp.asarray(mask, jnp.float32))
-            loss = float(res.loss)
+            with TraceAnnotation("trainer.quarantine"):
+                # non-finite loss/grad: the per-example norms (and losses)
+                # the pass already computed identify the poisoned examples;
+                # reweight them out through the plan's loss_weights= path
+                # and retry — skip examples, not steps (DESIGN.md §11)
+                mask = self._quarantine_mask(res)
+                if mask is None:
+                    self.events.append({"step": self.step, "kind": "skip_step",
+                                        "reason": "every example non-finite"})
+                    dt = time.perf_counter() - t0
+                    m = {"step": self.step, "loss": loss, "time_s": dt,
+                         "skipped": 1}
+                    self.metrics.append(m)
+                    return m          # previous params/opt_state kept
+                quarantined = int((~mask).sum())
+                self.events.append({
+                    "step": self.step, "kind": "quarantine",
+                    "examples": [int(i) for i in np.flatnonzero(~mask)]})
+                if self._step_fn_weighted is None:
+                    self._step_fn_weighted = self._build_step(weighted=True)
+                params, opt_state, err, res = self._step_fn_weighted(
+                    self.params, self.opt_state, self.err,
+                    self._substitute_rows(batch, mask), sub,
+                    jnp.asarray(mask, jnp.float32))
+                loss = float(res.loss)
         self.params, self.opt_state, self.err = params, opt_state, err
         jax.block_until_ready(res.loss)
         dt = time.perf_counter() - t0
@@ -242,9 +258,10 @@ class Trainer:
         if quarantined:
             m["quarantined"] = quarantined
         if res.sq_norms is not None:
-            sqs = jnp.sum(res.sq_norms, -1)
-            m["norm_mean"] = float(jnp.mean(jnp.sqrt(sqs)))
-            m["norm_max"] = float(jnp.max(jnp.sqrt(sqs)))
+            with TraceAnnotation("trainer.norm_stats"):
+                sqs = jnp.sum(res.sq_norms, -1)
+                m["norm_mean"] = float(jnp.mean(jnp.sqrt(sqs)))
+                m["norm_max"] = float(jnp.max(jnp.sqrt(sqs)))
         if res.gns is not None:
             m["gns"] = float(res.gns)
         self.metrics.append(m)
