@@ -18,9 +18,10 @@ With no option it runs, in one process, on the first TPU device:
                   Pallas against the XLA stats, and against the naive
                   per-example gradients of ``core/naive.py``.
 
-``--four-chips`` runs only the data-parallel trainer step over a 4-way
-data mesh (``dist.pex``) and the single-device ``Engine.step`` it must
-equal. Timings printed on the way are smoke numbers, not benchmarks.
+``--four-chips`` runs only the data-parallel path over a 4-way data
+mesh (``dist.pex``) against the single-device ``Engine.step``: the
+data-parallel engine step's gradients, leaf by leaf, and the trainer
+step's losses, norms, clip coefficients and placement. Timings printed on the way are smoke numbers, not benchmarks.
 
 It stops with a non-zero exit when JAX finds no TPU, and on the first
 phase that fails. The last line of standard output is, on success,
@@ -298,6 +299,24 @@ def phase_four_chips(jax, jnp, devices) -> None:
     trainer.params = jax.device_put(trainer.params, replicated)
     trainer.opt_state = jax.device_put(trainer.opt_state, replicated)
     batch_dp = jax.device_put(batch, NamedSharding(mesh, PartitionSpec("data")))
+    # the trainer's step returns no gradient tree (it donates the moments
+    # the update overwrites): the data-parallel gradients come from the
+    # same engine step it runs, jitted on its own
+    grads_dp = jax.jit(lambda p, b: trainer.engine.step(
+        loss_fn, p, b, consumers=_inject_rngs(consumers, key)).grads)(
+            trainer.params, batch_dp)
+    leaves = jax.tree_util.tree_leaves_with_path(grads_dp)
+    leaves_ref = jax.tree_util.tree_leaves(ref.grads)
+    if not leaves or len(leaves) != len(leaves_ref):
+        raise AssertionError(f"{len(leaves)} data-parallel gradient leaves "
+                             f"against {len(leaves_ref)} single-device ones")
+    for (path, g), g_ref in zip(leaves, leaves_ref):
+        check_close_fro(f"grad{jax.tree_util.keystr(path)}", g, g_ref, 2e-2,
+                        "four bf16 partial sums and a psum against one sum "
+                        "of 8; a lost or doubled psum is off by >= 0.5")
+    log(f"  {len(leaves)} gradient leaves compared")
+    del grads_dp, leaves
+    gc.collect()
     t0 = time.perf_counter()
     params, opt_state, _, res = trainer._step_fn(
         trainer.params, trainer.opt_state, trainer.err, batch_dp, key)
@@ -323,12 +342,6 @@ def phase_four_chips(jax, jnp, devices) -> None:
                 ref.sq_norms.sum(-1), 1e-2, rows_why)
     check_close("clip coefficients", res.clip_coef, ref.clip_coef, 1e-2,
                 rows_why)
-    for (path, g), g_ref in zip(
-            jax.tree_util.tree_leaves_with_path(res.grads),
-            jax.tree_util.tree_leaves(ref.grads)):
-        check_close_fro(f"grad{jax.tree_util.keystr(path)}", g, g_ref, 2e-2,
-                        "four bf16 partial sums and a psum against one sum "
-                        "of 8; a lost or doubled psum is off by >= 0.5")
     del res, ref
     trainer.params, trainer.opt_state = params, opt_state
     del params, opt_state

@@ -12,7 +12,10 @@ share one definition of each invariant:
   * ``step([Grads()])`` costs no more than plain ``value_and_grad``;
   * the Clip plan fits the one-forward budget
     ``cost(norms) + (cost(grad) − cost(forward))`` — one tapped
-    forward, one activation backward, ONE reweighted backward.
+    forward, one activation backward, ONE reweighted backward — and,
+    where it traced one backward (the book, ``core.book``), the
+    tighter ``cost(norms) + BOOK_SHARE·(cost(grad) − cost(forward))``:
+    the weight-gradient matmuls and no second activation backward.
 
 Each invariant exists at two levels: a pure arithmetic ``check_*``
 (takes costs, raises AssertionError — what the bench calls after
@@ -38,6 +41,10 @@ EQ_TOL = 1e-6
 BUDGET_TOL = 0.02
 #: Noise+GNS epsilon over the Clip plan alone (O(n_params) extras)
 EPS_TOL = 0.25
+#: a one-backward plan's share of a plain backward: the weight-gradient
+#: half of its matmuls (a third where the backward recomputes a
+#: rematerialized forward), with room for the per-example scaling
+BOOK_SHARE = 0.6
 
 
 def cost_of(fn, *args) -> Tuple[float, float]:
@@ -97,6 +104,15 @@ def check_backward_budget(f_plan: float, f_norms: float, f_grad: float,
     assert f_plan <= budget * (1 + tol), (
         f"plan exceeds the one-forward budget (a second forward crept "
         f"in?): {f_plan} vs budget {budget}")
+
+
+def check_booked_budget(f_plan: float, f_norms: float, f_grad: float,
+                        f_fwd: float, *, share: float = BOOK_SHARE) -> None:
+    budget = f_norms + share * (f_grad - f_fwd)
+    assert f_plan <= budget, (
+        f"a one-backward plan costs more than the norms pass plus the "
+        f"weight-gradient matmuls (a second backward crept in?): "
+        f"{f_plan} vs budget {budget}")
 
 
 def check_fused_epsilon(f_fused: float, f_base: float, *,
@@ -173,7 +189,9 @@ def assert_backward_budget(loss_fn, params, batch, consumers, *,
                            engine: Optional[Engine] = None,
                            tol: float = BUDGET_TOL) -> None:
     """A norm-consuming plan (Clip and friends) fits the one-forward
-    budget: cost(norms pass) + (cost(plain grad) − cost(plain fwd))."""
+    budget: cost(norms pass) + (cost(plain grad) − cost(plain fwd));
+    one that traced a single backward (``StepResult.backwards``) fits
+    the booked budget too."""
     from repro import pex
     eng = engine if engine is not None else Engine(
         PexSpec(enabled=True, method="gram"), clip_norm=1.0)
@@ -186,7 +204,14 @@ def assert_backward_budget(loss_fn, params, batch, consumers, *,
     f_norms, _ = cost_of(
         lambda p: eng.step(loss_fn, p, batch, [pex.Norms()]).sq_norms,
         params)
-    f_plan, _ = cost_of(
-        lambda p: eng.step(loss_fn, p, batch, list(consumers)).grads,
-        params)
+    traced = {}
+
+    def plan_grads(p):
+        res = eng.step(loss_fn, p, batch, list(consumers))
+        traced["backwards"] = res.backwards
+        return res.grads
+
+    f_plan, _ = cost_of(plan_grads, params)
     check_backward_budget(f_plan, f_norms, f_grad, f_fwd, tol=tol)
+    if traced["backwards"] == 1:
+        check_booked_budget(f_plan, f_norms, f_grad, f_fwd)

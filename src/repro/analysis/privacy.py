@@ -21,14 +21,26 @@ gradient"); a ``grad_seed`` marker keeps only the clip evidence and
 adds its kind; a ``noise`` marker replaces taint with its own site
 token (each leaf's noise is one marker — counting tokens per gradient
 leaf is the exactly-once proof); ``rng_use`` markers and the consumer
-key invars carry ``key``.
+key invars carry ``key``; a ``book`` marker (an array of the norms
+backward's book, ``core.book``, where the weighted contraction reads
+it) replaces its taint with {``book``}: per-example values, not yet
+summed, whose lineage to the norms seed is not a seed reaching the
+gradient — the contraction must still bring ``clip`` and
+``seed:weighted`` in; a ``book_fold`` marker adds ``fold`` where its
+input is a book value (``book``) already scaled by the weighted seed
+(``seed:weighted``) and still holds its batch axis (leading size =
+the marker's batch) — the evidence that the weights came in before
+the contraction summed over the batch.
 
 **Checks** (conditional on what the plan declares):
 
   * clip ⇒ every gradient leaf carries ``clip`` and ``seed:weighted``
     and no plain/norms seed — i.e. the only backward that built it was
     the clip-weighted one, which scales per example *before* the batch
-    sum by AD linearity; the clip marker's own input must carry
+    sum by AD linearity (or the book's contraction, which folds the
+    same weighted seed into each per-example entry before it sums over
+    the batch: a leaf that carries ``book`` must carry ``fold``); the
+    clip marker's own input must carry
     ``seed:norms`` (coefficients computed from the per-example norms
     backward) and its meta must match the plan's C;
   * noise ⇒ exactly one noise token per leaf, one marker per leaf
@@ -50,9 +62,9 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.analysis import _jaxpr as _J
 from repro.analysis.findings import ERROR, WARNING, Finding
-from repro.core.provenance import (KNOWN_TAGS, MARK_PRIMITIVE, TAG_CLIP,
-                                   TAG_GLEAF, TAG_NOISE, TAG_RNG, TAG_SAMPLE,
-                                   TAG_SEED, meta_dict)
+from repro.core.provenance import (KNOWN_TAGS, MARK_PRIMITIVE, TAG_BOOK,
+                                   TAG_CLIP, TAG_FOLD, TAG_GLEAF, TAG_NOISE,
+                                   TAG_RNG, TAG_SAMPLE, TAG_SEED, meta_dict)
 
 PASS = "privacy"
 _EMPTY = _J.EMPTY
@@ -60,6 +72,8 @@ _EMPTY = _J.EMPTY
 #: taint tokens
 T_CLIP = "clip"
 T_KEY = "key"
+T_BOOK = "book"
+T_FOLD = "fold"
 
 
 def _seed_tok(kind: str) -> str:
@@ -167,6 +181,18 @@ class _PrivacyWalker(_J.Walker):
         elif tag == TAG_RNG:
             token = T_KEY
             out = t_in | {T_KEY}
+        elif tag == TAG_BOOK:
+            # per-example book values: their norms-seed lineage is what
+            # they are, not an unweighted seed reaching the batch sum
+            token = T_BOOK
+            out = frozenset({T_BOOK})
+        elif tag == TAG_FOLD:
+            # the weights met a book value while its batch axis was whole
+            shape = eqn.invars[0].aval.shape
+            folded = T_BOOK in t_in and _seed_tok("weighted") in t_in \
+                and shape[:1] == (meta.get("batch"),)
+            token = T_FOLD if folded else None
+            out = t_in | {T_FOLD} if folded else t_in
         elif tag == TAG_GLEAF:
             # the plan/apply boundary marker (traffic pass anchor) —
             # pure identity for privacy lineage: the leaf's taint is
@@ -336,11 +362,12 @@ def analyze_trace(trace: _J.StepTrace) -> PrivacyReport:
             # noise sample added to every leaf (a frozen LoRA base
             # under stop_gradient). Nothing per-example flows into the
             # batch sum through it; skip the clip requirement.
-            if not any(t == T_CLIP or t.startswith("seed:")
+            if not any(t in (T_CLIP, T_BOOK) or t.startswith("seed:")
                        for t in lf.taint):
                 continue
             if T_CLIP not in lf.taint or \
-                    _seed_tok("weighted") not in lf.taint:
+                    _seed_tok("weighted") not in lf.taint or \
+                    (T_BOOK in lf.taint and T_FOLD not in lf.taint):
                 findings.append(Finding(
                     PASS, ERROR, "unclipped-leaf",
                     "gradient is not scaled by the per-example clip "

@@ -40,7 +40,10 @@ Findings:
     forward of the same model (× the importance-region factor);
   * ``dead-residual``       — a two-backward plan whose reweighted
     backward does not reuse the norms backward's residuals (a second
-    linearization doubles residual traffic);
+    linearization doubles residual traffic); where the plan took the
+    book (``core.book``), whose contraction reads no residuals, one
+    whose book was not kept by the norms backward that produced the
+    step's norms;
   * ``upcast-materialization`` — a widening dtype copy of a gradient
     leaf materialized as its own pass (f32 copies of bf16 trees).
 
@@ -53,8 +56,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.analysis import _jaxpr as _J
 from repro.analysis.findings import ERROR, Finding
-from repro.core.provenance import (MARK_PRIMITIVE, TAG_GLEAF, TAG_RNG,
-                                   TAG_SEED, meta_dict)
+from repro.core.provenance import (MARK_PRIMITIVE, TAG_BOOK, TAG_GLEAF,
+                                   TAG_RNG, TAG_SEED, meta_dict)
 
 PASS = "traffic"
 EMPTY = _J.EMPTY
@@ -249,10 +252,12 @@ class _TrafficWalker(_J.Walker):
 
     Taint tokens: ``p`` (parameters), ``opt`` (optimizer state), ``b``
     (batch), ``key``/``nz`` (PRNG lineage), ``seed:<kind>`` (backward
-    seeds), ``g:<i>`` (gradient leaf i, from the plan/apply boundary
-    markers). ``g:*`` is stripped at scalar reduction outputs so a
-    global-norm scalar does not smear every leaf's token over the
-    whole apply phase.
+    seeds), ``nb:<i>`` (which norms-seeded backward, one token per
+    seed marker), ``g:<i>`` (gradient leaf i, from the plan/apply
+    boundary markers). ``g:*`` is stripped at scalar reduction outputs
+    so a global-norm scalar does not smear every leaf's token over the
+    whole apply phase. ``book`` collects (bytes, taint) of each book
+    marker.
     """
 
     def __init__(self):
@@ -261,6 +266,8 @@ class _TrafficWalker(_J.Walker):
         self.trips = 1.0
         self._stack: List[int] = []       # enclosing container Rec idxs
         self.gleaf_sizes: Dict[int, int] = {}
+        self.book: List[Tuple[float, frozenset]] = []
+        self._nb: Dict[int, str] = {}
 
     # -- recording ---------------------------------------------------------
     def _record(self, eqn, kind, in_t) -> Rec:
@@ -312,8 +319,13 @@ class _TrafficWalker(_J.Walker):
                 self.gleaf_sizes[leaf] = _aval_size(eqn.outvars[0].aval)
         elif tag == TAG_SEED:
             t = t | {f"seed:{meta.get('kind', '?')}"}
+            if meta.get("kind") == "norms":
+                t = t | {self._nb.setdefault(id(eqn),
+                                             f"nb:{len(self._nb)}")}
         elif tag == TAG_RNG and meta.get("purpose") == "noise":
             t = t | {T_NOISEKEY}
+        elif tag == TAG_BOOK and self.recording:
+            self.book.append((_aval_bytes(eqn.outvars[0].aval), t))
         if self.recording:
             self._record(eqn, "mark", in_t)
         return [t]
@@ -602,8 +614,12 @@ def expected_streams(plan, optimizer: str,
 
 def _walk(closed, in_taints):
     walker = _TrafficWalker()
-    walker.run(closed, in_taints)
+    walker.out_taints = walker.run(closed, in_taints)
     return walker
+
+
+def _nb_tokens(taint) -> frozenset:
+    return frozenset(t for t in taint if t.startswith("nb:"))
 
 
 def program_flops(closed) -> Tuple[float, float]:
@@ -761,7 +777,27 @@ def analyze_trace(trace: _J.TrainTrace, *,
 
     # -- residual sharing --------------------------------------------------
     sharing = -1.0
-    if plan.n_backwards == 2 and plan.importance is None:
+    if plan.n_backwards == 2 and plan.importance is None and walker.book:
+        # the booked form: its contraction reads the book, not forward
+        # residuals — the entries a backward made (Z̄, per-example
+        # gradients; H is the forward's own) must come from the norms
+        # backward whose norms the step returns, not another
+        # linearization's
+        norms_nb = frozenset().union(*(
+            _nb_tokens(t) for t, (field, _) in
+            zip(walker.out_taints, trace.out_labels) if field == "sq_norms"))
+        made = [(b, _nb_tokens(t)) for b, t in walker.book if _nb_tokens(t)]
+        total_b = sum(b for b, _ in made)
+        shared = sum(b for b, nb in made if nb <= norms_nb)
+        sharing = shared / total_b if total_b > 0 else 0.0
+        if sharing < RESIDUAL_TOL:
+            findings.append(Finding(
+                PASS, ERROR, "dead-residual",
+                f"the booked contraction reads {total_b / 1e6:.1f} MB of "
+                f"book but only {sharing:.0%} of it was kept by the norms "
+                f"backward whose norms the step returns — the book comes "
+                f"from a second linearization"))
+    elif plan.n_backwards == 2 and plan.importance is None:
         fwd_out = {}
         for r in records:
             if r.phase == PH_FWD:

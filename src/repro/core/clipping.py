@@ -16,9 +16,10 @@ paper's extension: after the norms are known, each example's Z̄ rows
 are rescaled in place and the final backprop step
 W̄⁽ⁱ⁾' = X⁽ⁱ⁾ᵀ Z̄⁽ⁱ⁾'  is recomputed — no second backward pass. It
 requires materializing every (H, Z̄) pair, which is exactly what the
-paper's MLP setting affords; the production path for deep scanned LMs
-is the two-pass plan form (same result, O(batch) memory — see
-DESIGN.md §2).
+paper's MLP setting affords; for deep scanned LMs the plan layer runs
+the same idea as the book (``core.book``: entries kept by the norms
+backward, a bounded lifetime, a memory rule) and falls back to the
+two-pass form (O(batch) memory) — see DESIGN.md §2.
 
 Mechanism: "perturbation taps". The model forward is written as
 

@@ -32,6 +32,7 @@ its own contribution norm in the same fused pass.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -103,6 +104,11 @@ class Engine:
         self.clip_norm = clip_norm
         self.noise_std = noise_std
         self.granularity = granularity
+        #: bytes the clipped plan's book may take (``Plan.book_room``):
+        #: None for the device rule; a caller that has measured its
+        #: compiled step sets it (``Trainer``: 0 where the booked step
+        #: would not fit the device)
+        self._book_room: Optional[int] = None
 
     # ------------------------------------------------------------------
     def _adapt(self, loss_fn: Callable, layout,
@@ -110,10 +116,12 @@ class Engine:
         """Tap-collector loss → the explicit-acc loss the plan layer
         consumes; the Tap is created inside the traced function, per
         trace. Signature of the result:
-        ``acc_loss(params, acc, batch) -> (loss_vec, token_map|None,
-        acc_out, aux)`` (acc=None ⇒ inert tap ⇒ the plain model)."""
-        def acc_loss(params, acc, batch):
-            tap = Tap(self.spec, acc=acc, layout=layout)
+        ``acc_loss(params, acc, batch, book=None) -> (loss_vec,
+        token_map|None, acc_out, aux)`` (acc=None ⇒ inert tap ⇒ the
+        plain model; ``book``: the ``core.book.Booking`` the tap books
+        its sites under)."""
+        def acc_loss(params, acc, batch, book=None):
+            tap = Tap(self.spec, acc=acc, layout=layout, book=book)
             loss_vec, aux = loss_fn(params, batch, tap)
             tok = tap.token_losses() if want_token_map else None
             return loss_vec, tok, tap.carry(), aux
@@ -136,6 +144,7 @@ class Engine:
         ``consumers=()`` the program is the plain forward."""
         plan = plan_mod.analyze(consumers,
                                 engine_granularity=self.granularity)
+        plan = dataclasses.replace(plan, book_room=self._book_room)
         b = batch_size if batch_size is not None else infer_batch_size(batch)
         if plan.token_norms:
             layout = TokenLayout(seq if seq is not None

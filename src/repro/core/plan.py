@@ -25,7 +25,12 @@ and ``execute`` compiles it into the minimal program:
     every gradient-demanding consumer shares it. With no weights the
     norms and gradients fold into a single backward (paper §4/§5);
     with no consumers the taps are never created and the program is
-    the plain forward.
+    the plain forward;
+  * where the loss allows it, no second backward at all: the norms
+    backward also keeps each tap site's ``(H, Z̄)`` (the book,
+    ``core.book``) and the weighted gradient is the contraction
+    ``Σ_j w_j H_jᵀ Z̄_j`` over it (Bu et al. 2023's book-keeping
+    form). ``StepResult.backwards`` says which form was traced.
 
 Per-example weights enter as the cotangent seed of the (B,) loss
 vector. Per-token weights (``Clip(C, granularity="token")``) enter as
@@ -55,6 +60,7 @@ from typing import Any, Callable, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import book as book_mod
 from repro.core import importance as imp
 from repro.core.clipping import token_clip_coefficients
 from repro.core.passes import (add_grad_noise, add_grad_noise_segmented,
@@ -167,6 +173,10 @@ class Plan:
     needs_grads: bool = False
     token_norms: bool = False
     token_weighted: bool = False
+    #: bytes the book (``core.book``) may take: what the caller has
+    #: measured its compiled step to leave (``Engine`` sets it); None
+    #: for the device rule, ``core.book.default_room``
+    book_room: Optional[int] = None
 
     @property
     def weighted(self) -> bool:
@@ -181,7 +191,9 @@ class Plan:
         forward, 1 when norms and the unweighted gradient fold into
         one seed, 2 when a reweighted backward follows the norms pass.
         Never more — the structural claim the one-forward budget
-        (``analysis.plan_invariants``) pins on compiled HLO."""
+        (``analysis.plan_invariants``) pins on compiled HLO. An upper
+        bound: where the loss takes the book, the reweighted plan runs
+        one (``StepResult.backwards``)."""
         if not self.needs_norms and not self.needs_grads:
             return 0
         if not self.needs_norms:
@@ -322,16 +334,35 @@ class StepResult(NamedTuple):
     gns: Optional[jax.Array] = None
     sample: Optional[imp.ImportanceSample] = None
     sub_sq_norms: Optional[jax.Array] = None  # pool norms on the sub-batch
+    #: backward applications the traced program runs, over all its
+    #: regions: a Python int fixed at trace time (2 for a reweighted
+    #: plan that kept both backwards, 1 where the book replaced the
+    #: second)
+    backwards: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
 # the fused single-region core
 # ---------------------------------------------------------------------------
 
-def _vjp(acc_loss: Callable, params, batch, acc0, token_weighted: bool):
+def _vjp(acc_loss: Callable, params, batch, acc0, token_weighted: bool,
+         level=None):
     """One tapped forward via ``jax.vjp``; the returned vjp_fn is
     applied up to twice (norms seed / weight seed) over the shared
-    residuals, each application DCE'd to its live outputs."""
+    residuals, each application DCE'd to its live outputs. With a book
+    ``level`` the forward also takes the book's zero slots, whose
+    cotangents are the book entries."""
+    if level is not None:
+        def f(p, acc, slots):
+            lv, _tok, acc_out, aux = acc_loss(
+                p, acc, batch, book_mod.Booking(book_mod.RECORD, level,
+                                                slots))
+            return lv, (acc_out, aux)
+
+        lv, vjp_fn, (_, aux) = jax.vjp(f, params, acc0,
+                                       book_mod.zeros(level), has_aux=True)
+        return lv, None, aux, vjp_fn
+
     if token_weighted:
         def f(p, acc):
             lv, tok, acc_out, aux = acc_loss(p, acc, batch)
@@ -358,16 +389,23 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
     """Execute the single-region part of a plan (no importance split,
     no noise, no GNS — drivers add those): one forward, ≤ 2 backward
     applications. Returns ``(loss_vec, aux, sq_norms, grads, weights,
-    token_weights, clip_coef)`` with undemanded entries None.
+    token_weights, clip_coef, backwards)`` with undemanded entries None;
+    ``backwards`` is the number of backward applications traced (a
+    Python int).
 
     Usable directly inside a ``shard_map`` body (``dist.pex``) — all
     per-example quantities are computed shard-locally.
+
+    A reweighted example-granularity plan takes the book (``core.book``)
+    where the loss allows it: the norms backward also returns every tap
+    site's entry, and the gradient is their weighted contraction — one
+    backward, not two. Otherwise it keeps the second backward.
     """
     if not plan.needs_norms and not plan.needs_grads:
         # plain forward: the tap is never live, the program is the model
         with jax.named_scope(FORWARD):
             lv, _, _, aux = acc_loss(params, None, batch)
-        return lv, aux, None, None, None, None, None
+        return lv, aux, None, None, None, None, None, 0
 
     if not plan.needs_norms:
         # gradient pass only (possibly user-weighted): no instrumentation
@@ -382,12 +420,17 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
             else mark_seed(jnp.ones_like(lv), kind="plain")
         with jax.named_scope(WEIGHT_BACKWARD):
             (grads,) = vjp_fn(seed)
-        return (lv, aux, None, grads, loss_weights, None, None)
+        return lv, aux, None, grads, loss_weights, None, None, 1
 
     acc0 = layout.init(batch_size)
+    folded = plan.needs_grads and not plan.weighted and loss_weights is None
+    level = None
+    if plan.needs_grads and not folded and not plan.token_weighted:
+        level = book_mod.plan(acc_loss, params, batch, acc0,
+                              plan.book_room)
     with jax.named_scope(FORWARD):
         lv, tok, aux, vjp_fn = _vjp(acc_loss, params, batch, acc0,
-                                    plan.token_weighted)
+                                    plan.token_weighted, level)
     if plan.token_weighted and tok.shape[:2] != (lv.shape[0], layout.seq):
         raise ValueError(
             f"the registered per-token loss map has shape {tok.shape}, "
@@ -403,10 +446,14 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
                  else jnp.zeros_like(tok)),)
 
     grads = None
-    if plan.needs_grads and not plan.weighted and loss_weights is None:
+    if folded:
         # norms and gradients fold into ONE backward (paper §4/§5)
         with jax.named_scope(WEIGHT_BACKWARD):
             grads, sq = vjp_fn(*seeds(mark_seed(ones, kind="plain")))
+    elif level is not None:
+        # dW chains dead → DCE; the book entries come back with the norms
+        with jax.named_scope(NORMS_BACKWARD):
+            _, sq, entries = vjp_fn(mark_seed(ones, kind="norms"))
     else:
         # dW chains dead → DCE
         with jax.named_scope(NORMS_BACKWARD):
@@ -414,6 +461,13 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
 
     with jax.named_scope(NORMS_BACKWARD):
         w, tw, cc = _compose_weights(plan, sq, loss_weights)
+    if level is not None:
+        with jax.named_scope(WEIGHT_BACKWARD):
+            seed = mark_seed(ones, kind="plain") if w is None \
+                else mark_seed(w.astype(lv.dtype), kind="weighted")
+            grads = book_contraction(acc_loss, params, batch, level,
+                                     entries, seed)
+        return lv, aux, sq, grads, w, tw, cc, 1
     if plan.needs_grads and grads is None:
         with jax.named_scope(WEIGHT_BACKWARD):
             if tw is not None:
@@ -425,7 +479,15 @@ def run_fused(plan: Plan, acc_loss: Callable, params, batch,
                 seed = mark_seed(ones, kind="plain") if w is None \
                     else mark_seed(w.astype(lv.dtype), kind="weighted")
                 grads, _ = vjp_fn(*seeds(seed))
-    return lv, aux, sq, grads, w, tw, cc
+        return lv, aux, sq, grads, w, tw, cc, 2
+    return lv, aux, sq, grads, w, tw, cc, 1
+
+
+def book_contraction(acc_loss: Callable, params, batch, level, entries,
+                     weights):
+    """The reweighted gradient ``Σ_j w_j ∂L_j/∂θ`` from the norms
+    backward's book (``core.book.grads``)."""
+    return book_mod.grads(acc_loss, params, batch, level, entries, weights)
 
 
 def _compose_weights(plan: Plan, sq_norms, loss_weights,
@@ -478,12 +540,12 @@ def execute(plan: Plan, acc_loss: Callable, params, batch,
 
     samp = sub_sq = None
     if plan.importance is None:
-        lv, aux, sq, grads, w, tw, cc = fused_fn(plan, batch, batch_size,
-                                                 loss_weights)
+        lv, aux, sq, grads, w, tw, cc, backwards = fused_fn(
+            plan, batch, batch_size, loss_weights)
     else:
         ip = plan.importance
-        lv, aux, sq, _, _, _, _ = fused_fn(_NORMS_ONLY, batch, batch_size,
-                                           None)
+        lv, aux, sq, _, _, _, _, backwards = fused_fn(
+            _NORMS_ONLY, batch, batch_size, None)
         samp = imp.sample(ip.rng, sq, ip.k, smoothing=ip.smoothing,
                           replace=ip.replace)
         sub_batch = imp.gather_batch(batch, samp.indices,
@@ -496,8 +558,9 @@ def execute(plan: Plan, acc_loss: Callable, params, batch,
             extra_weights=samp.weights)
         grads = None
         if plan.needs_grads:
-            _, _, _, grads, w, _, _ = fused_fn(_GRADS_ONLY, sub_batch,
-                                               ip.k, w)
+            _, _, _, grads, w, _, _, n = fused_fn(_GRADS_ONLY, sub_batch,
+                                                  ip.k, w)
+            backwards += n
 
     gns = None
     if plan.gns:
@@ -523,7 +586,7 @@ def execute(plan: Plan, acc_loss: Callable, params, batch,
                 grads = add_grad_noise(grads, plan.noise.noise_std, scale,
                                        plan.noise.rng)
     return StepResult(jnp.sum(lv), lv, aux, sq, grads, w, tw, cc, gns,
-                      samp, sub_sq)
+                      samp, sub_sq, backwards)
 
 
 # ---------------------------------------------------------------------------

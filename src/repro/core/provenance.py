@@ -24,7 +24,14 @@ semantic anchor:
   * ``noise``      — one leaf's DP noise sample, meta: noise_std,
     scale, leaf index;
   * ``rng_use``    — a PRNG key at its point of consumption, meta:
-    purpose + index (the key-lineage single-use check hangs off these).
+    purpose + index (the key-lineage single-use check hangs off these);
+  * ``book``       — one array of the norms backward's book (``core.
+    book``: a site's H, Z̄ or per-example gradient) where the weighted
+    contraction reads it, meta: leaf index. Book values are per example,
+    not yet summed over the batch;
+  * ``book_fold``  — a book array scaled per example by the weights
+    (clip coefficient × user loss weight) while its leading batch axis
+    is still whole, meta: the batch size.
 
 Marker placement is production code (``core.passes``, ``core.plan``,
 ``core.clipping``, ``core.importance``) — the analyzer only *reads*
@@ -49,8 +56,10 @@ TAG_NOISE = "noise"
 TAG_RNG = "rng_use"
 TAG_SAMPLE = "sample_idx"
 TAG_GLEAF = "grad_leaf"
+TAG_BOOK = "book"
+TAG_FOLD = "book_fold"
 KNOWN_TAGS = frozenset({TAG_CLIP, TAG_SEED, TAG_NOISE, TAG_RNG, TAG_SAMPLE,
-                        TAG_GLEAF})
+                        TAG_GLEAF, TAG_BOOK, TAG_FOLD})
 
 mark_p = jex_core.Primitive(MARK_PRIMITIVE)
 mark_p.def_impl(lambda x, *, tag, meta: x)
@@ -126,6 +135,21 @@ def mark_grad_tree(grads):
     leaves, tree = tree_flatten(grads)
     return tree_unflatten(
         tree, [mark_grad_leaf(g, leaf=i) for i, g in enumerate(leaves)])
+
+
+def mark_book(entries):
+    """Mark every array of the book (a pytree) where the weighted
+    contraction reads it."""
+    from jax.tree_util import tree_flatten, tree_unflatten
+    leaves, tree = tree_flatten(entries)
+    return tree_unflatten(
+        tree, [mark(x, TAG_BOOK, leaf=i) for i, x in enumerate(leaves)])
+
+
+def mark_fold(x, *, batch: int):
+    """Mark a book array scaled per example by the weights, before the
+    contraction sums it over its leading (batch) axis."""
+    return mark(x, TAG_FOLD, batch=batch)
 
 
 def mark_rng(key, *, purpose: str, index: Optional[int] = None):

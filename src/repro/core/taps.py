@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import book as B
 from repro.core import norms as N
 from repro.dist.sharding import shard as _shard
 
@@ -391,25 +392,33 @@ def _int_zero_cotangent(x):
 # dense: z = h @ w        (the paper's layer; h (B,[S,]p_in), w (p_in,p_out))
 # ---------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _pex_dense(method: str, use_pallas: bool, group: int, layout,
-               h: jax.Array, w: jax.Array, acc: jax.Array):
+# ``book`` (static) and ``slot`` (the record pass's zero slot, None
+# otherwise): what the backward rule returns as the slot's cotangent —
+# the site's book entry (core/book.py). Dense books ``(H, Z̄)``, or
+# ``Z̄`` alone (``book="z"``) where an earlier site booked the same H.
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _pex_dense(method: str, use_pallas: bool, group: int, layout, book,
+               h: jax.Array, w: jax.Array, acc: jax.Array, slot):
     return jnp.einsum("...i,io->...o", h, w), acc
 
 
-def _pex_dense_fwd(method, use_pallas, group, layout, h, w, acc):
+def _pex_dense_fwd(method, use_pallas, group, layout, book, h, w, acc,
+                   slot):
     z = jnp.einsum("...i,io->...o", h, w)
     return (z, acc), (h, w)
 
 
-def _pex_dense_bwd(method, use_pallas, group, layout, res, cts):
+def _pex_dense_bwd(method, use_pallas, group, layout, book, res, cts):
     h, w = res
     zbar, acc_bar = cts
     dh = jnp.einsum("...o,io->...i", zbar, w).astype(h.dtype)
     dw = jnp.einsum("...i,...o->io", h, zbar).astype(w.dtype)
     with jax.named_scope(STATS):
         dacc = layout.add_dense(acc_bar, h, zbar, group, method, use_pallas)
-    return dh, dw, dacc
+    entry = None if book is None else \
+        ((zbar,) if book == "z" else (h, zbar))
+    return dh, dw, dacc, entry
 
 
 _pex_dense.defvjp(_pex_dense_fwd, _pex_dense_bwd)
@@ -523,23 +532,24 @@ _pex_dense_expert_grouped.defvjp(_pex_dense_expert_grouped_fwd,
 # bias_add: z = x + b      (paper folds b into W as a ones-column; same math)
 # ---------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _pex_bias(group: int, layout, x: jax.Array, b: jax.Array,
-              acc: jax.Array):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _pex_bias(group: int, layout, book, x: jax.Array, b: jax.Array,
+              acc: jax.Array, slot):
     return x + b, acc
 
 
-def _pex_bias_fwd(group, layout, x, b, acc):
+def _pex_bias_fwd(group, layout, book, x, b, acc, slot):
     return (x + b, acc), None
 
 
-def _pex_bias_bwd(group, layout, _, cts):
+def _pex_bias_bwd(group, layout, book, _, cts):
     zbar, acc_bar = cts
     reduce_axes = tuple(range(zbar.ndim - 1))
     db = jnp.sum(zbar, axis=reduce_axes).astype(zbar.dtype)
     with jax.named_scope(STATS):
         dacc = layout.add_bias(acc_bar, zbar, group)
-    return zbar, db, dacc
+        entry = None if book is None else (B.per_example_sum(zbar),)
+    return zbar, db, dacc, entry
 
 
 _pex_bias.defvjp(_pex_bias_fwd, _pex_bias_bwd)
@@ -549,17 +559,17 @@ _pex_bias.defvjp(_pex_bias_fwd, _pex_bias_bwd)
 # scale: z = g ⊙ h         (elementwise params: RMSNorm gains, decays, ...)
 # ---------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _pex_scale(group: int, layout, h: jax.Array, g: jax.Array,
-               acc: jax.Array):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _pex_scale(group: int, layout, book, h: jax.Array, g: jax.Array,
+               acc: jax.Array, slot):
     return h * g, acc
 
 
-def _pex_scale_fwd(group, layout, h, g, acc):
+def _pex_scale_fwd(group, layout, book, h, g, acc, slot):
     return (h * g, acc), (h, g)
 
 
-def _pex_scale_bwd(group, layout, res, cts):
+def _pex_scale_bwd(group, layout, book, res, cts):
     h, g = res
     zbar, acc_bar = cts
     dh = (zbar * g).astype(h.dtype)
@@ -567,7 +577,9 @@ def _pex_scale_bwd(group, layout, res, cts):
     dg = jnp.sum(zbar * h, axis=reduce_axes).astype(g.dtype)
     with jax.named_scope(STATS):
         dacc = layout.add_scale(acc_bar, h, zbar, group)
-    return dh, dg, dacc
+        entry = None if book is None else (B.per_example_sum(
+            h.astype(_ACC_DTYPE) * zbar.astype(_ACC_DTYPE)),)
+    return dh, dg, dacc, entry
 
 
 _pex_scale.defvjp(_pex_scale_fwd, _pex_scale_bwd)
@@ -578,19 +590,19 @@ _pex_scale.defvjp(_pex_scale_fwd, _pex_scale_bwd)
 #                              exact via sort + segment-sum, O(S·d))
 # ---------------------------------------------------------------------------
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _pex_embed(group: int, layout, table: jax.Array, ids: jax.Array,
-               acc: jax.Array):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _pex_embed(group: int, layout, book, table: jax.Array, ids: jax.Array,
+               acc: jax.Array, slot):
     return jnp.take(table, ids, axis=0), acc
 
 
-def _pex_embed_fwd(group, layout, table, ids, acc):
+def _pex_embed_fwd(group, layout, book, table, ids, acc, slot):
     # `table` rides along only as a shape/dtype reference for the scatter;
     # it is a live parameter anyway, so this costs no extra memory.
     return (jnp.take(table, ids, axis=0), acc), (ids, table)
 
 
-def _pex_embed_bwd(group, layout, res, cts):
+def _pex_embed_bwd(group, layout, book, res, cts):
     ids, table = res
     zbar, acc_bar = cts
     flat_ids = ids.reshape(-1)
@@ -598,7 +610,8 @@ def _pex_embed_bwd(group, layout, res, cts):
     dtable = jnp.zeros_like(table).at[flat_ids].add(flat_z.astype(table.dtype))
     with jax.named_scope(STATS):
         dacc = layout.add_embedding(acc_bar, ids, zbar, group)
-    return dtable, _int_zero_cotangent(ids), dacc
+    entry = None if book is None else (zbar,)
+    return dtable, _int_zero_cotangent(ids), dacc, entry
 
 
 _pex_embed.defvjp(_pex_embed_fwd, _pex_embed_bwd)
@@ -688,16 +701,23 @@ class Tap:
     A tap with ``spec.enabled=False`` or no accumulator is inert: every
     op is its plain counterpart (``NULL`` is the shared inert tap), so
     the same model code serves uninstrumented.
+
+    A live tap handed a ``Booking`` (``book``: the plan layer traces
+    the loss for the book, ``core.book``) books its sites: its dense,
+    bias, scale and embedding ops list them, hand back their book
+    entries, or replay them (``_booked``); the other ops have no booked
+    form.
     """
-    __slots__ = ("spec", "layout", "_acc", "_token_losses")
+    __slots__ = ("spec", "layout", "_acc", "_token_losses", "_book")
 
     def __init__(self, spec: PexSpec, acc: Optional[jax.Array] = None,
-                 layout=None):
+                 layout=None, book: Optional["B.Booking"] = None):
         self.spec = spec
         self.layout = layout if layout is not None \
             else ExampleLayout(spec.n_groups)
         self._acc = acc
         self._token_losses = None
+        self._book = book if self.live else None
 
     @property
     def live(self) -> bool:
@@ -731,16 +751,49 @@ class Tap:
         """The registered per-token loss map for this trace (or None)."""
         return self._token_losses
 
+    # -- the book (core/book.py) -----------------------------------------
+    def _booked(self, kind: str, op, w, bookable: bool, h=None, ids=None):
+        """The site ``op(w)`` under the booking: ``(done, z, book,
+        slot)``. Discover lists the site (``op(stop_gradient(w))`` keeps
+        the forward's data path and cuts the weight's); replay routes
+        the site's weighted contraction to ``w``; record returns what
+        the op's backward rule books (``book``) and the zero slot that
+        carries it."""
+        b = self._book
+        if b is None:
+            return False, None, None, None
+        if b.mode == B.DISCOVER:
+            z = op(jax.lax.stop_gradient(w))
+            if not bookable:
+                b.refuse()
+            elif kind == "dense":
+                b.site(kind, ((h.shape, h.dtype), (z.shape, z.dtype)), h=h)
+            elif kind == "embedding":
+                b.site(kind, ((z.shape, z.dtype),))
+            else:                       # the (B, p) per-example gradient
+                b.site(kind, (((z.shape[0], w.shape[0]), _ACC_DTYPE),))
+            return True, z, None, None
+        if b.mode == B.REPLAY:
+            return True, B.replay(b, self, kind, w, jax.eval_shape(op, w),
+                                  ids=ids), None, None
+        site, slot = b.site(kind, h=h)
+        return False, None, ("z" if site.h_from is not None else "hz"), slot
+
     # -- ops -------------------------------------------------------------
     def dense(self, h, w, *, group: str = "all",
               method: Optional[str] = None) -> jax.Array:
         """Instrumented matmul. Plain einsum when the tap is inert."""
         if not self.live:
             return jnp.einsum("...i,io->...o", h, w)
+        done, z, book, slot = self._booked(
+            "dense", lambda v: jnp.einsum("...i,io->...o", h, v), w,
+            w.ndim == 2 and h.ndim >= 2, h=h)
+        if done:
+            return z
         m = method or self.spec.method
         z, self._acc = _pex_dense(m, self.spec.use_pallas,
                                   self.spec.group_index(group), self.layout,
-                                  h, w, self._acc)
+                                  book, h, w, self._acc, slot)
         return z
 
     def dense_batched(self, h, w, *, group: str = "all",
@@ -752,6 +805,9 @@ class Tap:
         estimator, same knob as the MoE expert taps)."""
         if not self.live:
             return jnp.einsum("b...i,bio->b...o", h, w)
+        if self._book is not None:          # no booked form
+            self._book.refuse()
+            return jnp.einsum("b...i,bio->b...o", h, w)
         m = method or self.spec.seg_method
         z, self._acc = _pex_dense_batched(
             m, self.spec.use_pallas, self.spec.group_index(group),
@@ -761,22 +817,37 @@ class Tap:
     def bias_add(self, x, b, *, group: str = "all") -> jax.Array:
         if not self.live:
             return x + b
+        done, z, book, slot = self._booked(
+            "bias_add", lambda v: x + v, b,
+            b.ndim == 1 and x.ndim >= 2 and x.shape[-1] == b.shape[0])
+        if done:
+            return z
         z, self._acc = _pex_bias(self.spec.group_index(group), self.layout,
-                                 x, b, self._acc)
+                                 book, x, b, self._acc, slot)
         return z
 
     def scale(self, h, g, *, group: str = "all") -> jax.Array:
         if not self.live:
             return h * g
+        done, z, book, slot = self._booked(
+            "scale", lambda v: h * v, g,
+            g.ndim == 1 and h.ndim >= 2 and h.shape[-1] == g.shape[0])
+        if done:
+            return z
         z, self._acc = _pex_scale(self.spec.group_index(group), self.layout,
-                                  h, g, self._acc)
+                                  book, h, g, self._acc, slot)
         return z
 
     def embedding(self, table, ids, *, group: str = "embed") -> jax.Array:
         if not (self.live and self.spec.tap_embeddings):
             return jnp.take(table, ids, axis=0)
+        done, z, book, slot = self._booked(
+            "embedding", lambda v: jnp.take(v, ids, axis=0), table,
+            table.ndim == 2 and ids.ndim >= 1, ids=ids)
+        if done:
+            return z
         z, self._acc = _pex_embed(self.spec.group_index(group), self.layout,
-                                  table, ids, self._acc)
+                                  book, table, ids, self._acc, slot)
         return z
 
     def _expert_tok(self, seg, tok):
@@ -803,6 +874,9 @@ class Tap:
         for TokenLayout."""
         if not self.live:
             return jnp.einsum("ecd,edf->ecf", x, w)
+        if self._book is not None:          # no booked form
+            self._book.refuse()
+            return jnp.einsum("ecd,edf->ecf", x, w)
         tok = self._expert_tok(seg, tok)
         z, self._acc = _pex_dense_expert(
             self.spec.group_index(group), self._acc.shape[0],
@@ -817,6 +891,9 @@ class Tap:
         (G,E,C) group-local flat token ids (>= bg·S ⇒ padding row),
         required for TokenLayout."""
         if not self.live:
+            return jnp.einsum("gecd,edf->gecf", x, w)
+        if self._book is not None:          # no booked form
+            self._book.refuse()
             return jnp.einsum("gecd,edf->gecf", x, w)
         tok = self._expert_tok(seg, tok)
         z, self._acc = _pex_dense_expert_grouped(
@@ -842,22 +919,48 @@ def scan(body, init, xs, *, tap: Optional[Tap] = None, length=None,
     scan boundary. ``remat=True`` applies ``jax.checkpoint`` (with
     ``policy``) to the (acc-explicit) body. With an inert/absent tap
     this is a plain ``lax.scan`` — the DCE property is untouched.
+
+    Under a booking (``core.book``) the body is a level of the book of
+    its own: the scan takes the level's slots or entries as extra
+    ``xs``, so the record pass's entries come back stacked on the
+    scan's axis. The replay needs no remat: its body keeps no
+    activations.
     """
     if tap is None or not tap.live:
         fn = jax.checkpoint(body, policy=policy) if remat else body
         return jax.lax.scan(fn, init, xs, length=length, reverse=reverse,
                             unroll=unroll)
 
-    def threaded(carry, x):
-        c, acc = carry
-        tap.set_carry(acc)
-        c, y = body(c, x)
-        return (c, tap.carry()), y
+    book = tap._book
+    if book is None:
+        def threaded(carry, x):
+            c, acc = carry
+            tap.set_carry(acc)
+            c, y = body(c, x)
+            return (c, tap.carry()), y
+    else:
+        entries = book.scan_xs()
+        level = []
+        xs = (xs, entries)
+        remat = remat and book.mode != B.REPLAY
+
+        def threaded(carry, x):
+            c, acc = carry
+            x, e = x
+            book.enter(e)
+            tap.set_carry(acc)
+            c, y = body(c, x)
+            level[:] = book.leave()
+            return (c, tap.carry()), y
 
     fn = jax.checkpoint(threaded, policy=policy) if remat else threaded
     (c, acc), ys = jax.lax.scan(fn, (init, tap.carry()), xs, length=length,
                                 reverse=reverse, unroll=unroll)
     tap.set_carry(acc)
+    if book is not None:
+        n = length if length is not None else \
+            jax.tree_util.tree_leaves(xs[0])[0].shape[0]
+        book.close_scan(n, level)
     return c, ys
 
 

@@ -231,11 +231,14 @@ def plan_step(plan, acc_loss: Callable, params, batch, batch_size: int, *,
         has_cc = sub.clip is not None
         has_grads = sub.needs_grads
 
+        backwards = []      # traced once: the region's backward count
+
         def run(p, bb, *maybe_lw):
             with shd.use_rules(None, {}):
-                lv, aux, sq, grads, w, tw, cc = plan_mod.run_fused(
+                lv, aux, sq, grads, w, tw, cc, n = plan_mod.run_fused(
                     sub, acc_loss, p, bb, local_b, layout,
                     loss_weights=maybe_lw[0] if maybe_lw else None)
+            backwards.append(n)
             _reject_aux(aux)
             outs = [lv]
             if has_sq:
@@ -261,7 +264,7 @@ def plan_step(plan, acc_loss: Callable, params, batch, batch_size: int, *,
         cc = res.pop(0) if has_cc else None
         tw = cc if sub.token_weighted else None
         grads = res.pop(0) if has_grads else None
-        return lv, {}, sq, grads, w, tw, cc
+        return lv, {}, sq, grads, w, tw, cc, backwards[0]
 
     return plan_mod.execute(plan, acc_loss, params, batch, batch_size,
                             layout, loss_weights=loss_weights,

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+from functools import partial
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -34,6 +35,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.ckpt.checkpoint import CheckpointManager
+from repro.core import book
 from repro.core import plan as plan_mod
 from repro.core.engine import Engine
 from repro.core.taps import PexSpec
@@ -147,26 +149,52 @@ class Trainer:
         self.metrics: list = []
         #: graceful-degradation log: quarantine / skip events
         self.events: list = []
+        #: backward applications of the step program as last traced
+        #: (``StepResult.backwards``), by whether it is the weighted
+        #: quarantine retry; reported as ``pex_backwards``
+        self._backwards: Dict[bool, int] = {}
         self._step_fn = self._build_step()
         self._step_fn_weighted = None       # built on first quarantine
+        self._fitted: set = set()           # steps whose memory was checked
 
     # ------------------------------------------------------------------
     def _build_step(self, weighted: bool = False):
+        """The jitted step. It donates the state the trainer owns (the
+        optimizer moments and the compression error carry) and returns
+        no gradient tree, so the update is written in place of the
+        moments it reads. A step whose loss or per-example norms are
+        not finite returns the moments and carry it was given, so that
+        ``run_step``'s quarantine retry and its skip start from the
+        state before the step (the parameters are not donated; there
+        ``run_step`` keeps its own)."""
         loss_fn, opt_cfg = self.loss_fn, self.opt_cfg
         consumers, eng = self.consumers, self.engine
+        traced = self._backwards
 
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(1, 2))
         def step_fn(params, opt_state, err, batch, rng, *loss_weights):
             res = eng.step(loss_fn, params, batch,
                            consumers=_inject_rngs(consumers, rng),
                            loss_weights=loss_weights[0] if weighted
                            else None)
-            grads = res.grads
+            traced[weighted] = res.backwards
+            grads, new_err = res.grads, err
             if err is not None:
-                grads, err = grad_compress.compress_decompress(grads, err)
-            params, opt_state = adamw.update(opt_cfg, opt_state, params,
-                                             grads)
-            return params, opt_state, err, res
+                grads, new_err = grad_compress.compress_decompress(grads,
+                                                                   err)
+            params, new_opt = adamw.update(opt_cfg, opt_state, params,
+                                           grads)
+            ok = jnp.isfinite(res.loss)
+            if res.sq_norms is not None:
+                ok = ok & jnp.all(jnp.isfinite(res.sq_norms))
+            # fuses into the update's own pass, whose scope it takes
+            with jax.named_scope(adamw.SCOPE):
+                keep = partial(jax.tree_util.tree_map,
+                               lambda new, old: jnp.where(ok, new, old))
+                new_opt, new_err = keep(new_opt, opt_state), \
+                    keep(new_err, err)
+            return (params, new_opt, new_err,
+                    res._replace(grads=None, backwards=None))
 
         return step_fn
 
@@ -205,8 +233,31 @@ class Trainer:
         """The step program that ``run_step`` runs on ``batch``, lowered
         and not run (``.compile().as_text()`` is the HLO the device
         runs). The trainer's state is left as it is."""
-        return self._step_fn.lower(self.params, self.opt_state, self.err,
-                                   batch, self.rng)
+        args = (self.params, self.opt_state, self.err, batch, self.rng)
+        return self._step(False, *args).lower(*args)
+
+    def _step(self, weighted: bool, *args):
+        """The jitted step ``run_step`` calls on ``args`` (the weighted
+        quarantine retry's built on first use). Its first use checks the
+        compiled program's memory against the device
+        (``book.step_overflows``): where the step took the book
+        (``core.book``) and would not fit, the engine's book room is
+        set to 0 and the step is built again with the second backward.
+        The check's compile is the one the call would make."""
+        if weighted and self._step_fn_weighted is None:
+            self._step_fn_weighted = self._build_step(weighted=True)
+        fn = self._step_fn_weighted if weighted else self._step_fn
+        if weighted not in self._fitted:
+            self._fitted.add(weighted)
+            if book.step_overflows(fn, *args) and \
+                    self._backwards.get(weighted) == 1:
+                self.engine._book_room = 0
+                fn = self._build_step(weighted)
+                if weighted:
+                    self._step_fn_weighted = fn
+                else:
+                    self._step_fn = fn
+        return fn
 
     def run_step(self, batch) -> Dict:
         """One step. The host's parts are ``jax.profiler``
@@ -216,8 +267,9 @@ class Trainer:
         t0 = time.perf_counter()
         with TraceAnnotation("trainer.dispatch"):
             self.rng, sub = jax.random.split(self.rng)
-            params, opt_state, err, res = self._step_fn(
-                self.params, self.opt_state, self.err, batch, sub)
+            args = (self.params, self.opt_state, self.err, batch, sub)
+            params, self.opt_state, self.err, res = \
+                self._step(False, *args)(*args)
         with TraceAnnotation("trainer.fetch_loss"):
             loss = float(res.loss)
         bad = not np.isfinite(loss)
@@ -237,24 +289,25 @@ class Trainer:
                                         "reason": "every example non-finite"})
                     dt = time.perf_counter() - t0
                     m = {"step": self.step, "loss": loss, "time_s": dt,
-                         "skipped": 1}
+                         "skipped": 1,
+                         "pex_backwards": self._backwards[False]}
                     self.metrics.append(m)
                     return m          # previous params/opt_state kept
                 quarantined = int((~mask).sum())
                 self.events.append({
                     "step": self.step, "kind": "quarantine",
                     "examples": [int(i) for i in np.flatnonzero(~mask)]})
-                if self._step_fn_weighted is None:
-                    self._step_fn_weighted = self._build_step(weighted=True)
-                params, opt_state, err, res = self._step_fn_weighted(
-                    self.params, self.opt_state, self.err,
-                    self._substitute_rows(batch, mask), sub,
-                    jnp.asarray(mask, jnp.float32))
+                args = (self.params, self.opt_state, self.err,
+                        self._substitute_rows(batch, mask), sub,
+                        jnp.asarray(mask, jnp.float32))
+                params, self.opt_state, self.err, res = \
+                    self._step(True, *args)(*args)
                 loss = float(res.loss)
-        self.params, self.opt_state, self.err = params, opt_state, err
+        self.params = params
         jax.block_until_ready(res.loss)
         dt = time.perf_counter() - t0
-        m = {"step": self.step, "loss": loss, "time_s": dt}
+        m = {"step": self.step, "loss": loss, "time_s": dt,
+             "pex_backwards": self._backwards[bool(quarantined)]}
         if quarantined:
             m["quarantined"] = quarantined
         if res.sq_norms is not None:
@@ -335,6 +388,7 @@ class Trainer:
                              data_axes=self.data_axes)
         self._step_fn = self._build_step()
         self._step_fn_weighted = None
+        self._fitted = set()
 
     # ------------------------------------------------------------------
     def train(self, resume: bool = False) -> list:

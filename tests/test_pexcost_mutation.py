@@ -66,10 +66,10 @@ def test_duplicated_forward_is_detected(monkeypatch):
     real = plan_mod.run_fused
 
     def doubled(plan, acc_loss, params, batch, bs, layout, **kw):
-        lv, aux, sq, grads, w, tw, cc = real(plan, acc_loss, params,
+        lv, aux, sq, grads, w, tw, cc, n = real(plan, acc_loss, params,
                                              batch, bs, layout, **kw)
         lv2, *_ = real(plan, acc_loss, params, batch, bs, layout, **kw)
-        return lv + 0.0 * lv2, aux, sq, grads, w, tw, cc
+        return lv + 0.0 * lv2, aux, sq, grads, w, tw, cc, n
 
     monkeypatch.setattr(plan_mod, "run_fused", doubled)
     rep = _verify(loss_fn, params, batch)
@@ -87,11 +87,11 @@ def test_dropped_residual_sharing_is_detected(monkeypatch):
     real = plan_mod.run_fused
 
     def relinearized(plan, acc_loss, params, batch, bs, layout, **kw):
-        lv, aux, sq, _, w, tw, cc = real(plan, acc_loss, params,
+        lv, aux, sq, _, w, tw, cc, n = real(plan, acc_loss, params,
                                          batch, bs, layout, **kw)
         _, _, _, grads, *_ = real(plan, acc_loss, params, batch, bs,
                                   layout, **kw)
-        return lv, aux, sq, grads, w, tw, cc
+        return lv, aux, sq, grads, w, tw, cc, n
 
     monkeypatch.setattr(plan_mod, "run_fused", relinearized)
     rep = _verify(loss_fn, params, batch)
@@ -142,10 +142,10 @@ def test_mutants_fire_through_the_cli_gate(monkeypatch):
     real = plan_mod.run_fused
 
     def doubled(plan, acc_loss, params, batch, bs, layout, **kw):
-        lv, aux, sq, grads, w, tw, cc = real(plan, acc_loss, params,
+        lv, aux, sq, grads, w, tw, cc, n = real(plan, acc_loss, params,
                                              batch, bs, layout, **kw)
         lv2, *_ = real(plan, acc_loss, params, batch, bs, layout, **kw)
-        return lv + 0.0 * lv2, aux, sq, grads, w, tw, cc
+        return lv + 0.0 * lv2, aux, sq, grads, w, tw, cc, n
 
     monkeypatch.setattr(plan_mod, "run_fused", doubled)
     assert main(["--arch", "llama3.2-1b", "--fast", "--cost",

@@ -19,6 +19,8 @@ resolve at call time — to build the classic DP-pipeline bugs:
   * noise added per shard inside the region (before the psum);
   * noise applied twice to the reduced gradient;
   * clip coefficients computed but never folded into the backward seed;
+  * the booked gradient's contraction run without the clip coefficients;
+  * the booked contraction scaling by the weights after the batch sum;
   * one PRNG key shared by every leaf's noise draw;
   * a per-example output psum'd over the data axis;
   * the data pipeline's seed ignoring the step cursor.
@@ -38,6 +40,7 @@ from repro.analysis import collectives as col
 from repro.analysis import coverage as cov
 from repro.analysis import determinism as det
 from repro.analysis import privacy as priv
+from repro.core import book
 from repro.core import plan as plan_mod
 from repro.core.provenance import mark_noise, mark_rng
 from repro.core.taps import Tap
@@ -175,14 +178,14 @@ def test_mutant_noise_before_psum(monkeypatch):
     real_noise = plan_mod.add_grad_noise
 
     def in_region(sub, acc_loss, p, b, bs, layout, *, loss_weights=None):
-        lv, aux, sq, grads, w, tw, cc = real_fused(
+        lv, aux, sq, grads, w, tw, cc, n = real_fused(
             sub, acc_loss, p, b, bs, layout, loss_weights=loss_weights)
         if sub.noise is not None and grads is not None:
             scale = sub.noise.scale if sub.noise.scale is not None \
                 else sub.clip.clip_norm
             grads = real_noise(grads, sub.noise.noise_std, scale,
                                sub.noise.rng)
-        return lv, aux, sq, grads, w, tw, cc
+        return lv, aux, sq, grads, w, tw, cc, n
 
     monkeypatch.setattr(plan_mod, "run_fused", in_region)
     monkeypatch.setattr(plan_mod, "add_grad_noise",
@@ -228,6 +231,42 @@ def test_mutant_unclipped_leaf(monkeypatch):
     assert "unclipped-leaf" in _codes(rep)
 
 
+def test_mutant_book_contraction_drops_clip(monkeypatch):
+    """The booked form (``core.book``) contracting the norms backward's
+    per-example entries with unit weights — the clip coefficients
+    dropped — sums every example unclipped."""
+    real = plan_mod.book_contraction
+
+    def unweighted(acc_loss, params, batch, level, entries, weights):
+        return real(acc_loss, params, batch, level, entries,
+                    jnp.ones(weights.shape, weights.dtype))
+
+    monkeypatch.setattr(plan_mod, "book_contraction", unweighted)
+    rep = priv.analyze_trace(_dp_trace())
+    assert any(m.tag == "book" for m in rep.marks)
+    assert not rep.ok
+    assert "unclipped-leaf" in _codes(rep)
+
+
+def test_mutant_book_weights_after_the_batch_sum(monkeypatch):
+    """The booked contraction summing each site's entries over the
+    batch first and scaling the sum by the mean weight — the clip
+    coefficients applied after the batch sum, not per example."""
+    real = book.contract
+
+    def late(kind, entry, w, shape, dtype):
+        g = real(kind, entry, jnp.ones_like(w), shape, dtype)
+        return (g * jnp.mean(w)).astype(dtype)
+
+    clean = priv.analyze_trace(_dp_trace())
+    assert clean.ok and any(m.tag == "book_fold" for m in clean.marks)
+    monkeypatch.setattr(book, "contract", late)
+    rep = priv.analyze_trace(_dp_trace())
+    assert not any(m.tag == "book_fold" and m.token for m in rep.marks)
+    assert not rep.ok
+    assert "unclipped-leaf" in _codes(rep)
+
+
 def test_mutant_reused_key(monkeypatch):
     """Every leaf's noise drawn from the SAME key — perfectly
     correlated noise across leaves, not independent Gaussians."""
@@ -254,9 +293,9 @@ def test_mutant_per_example_psum(monkeypatch):
     real_fused = plan_mod.run_fused
 
     def psum_lv(sub, acc_loss, p, b, bs, layout, *, loss_weights=None):
-        lv, aux, sq, grads, w, tw, cc = real_fused(
+        lv, aux, sq, grads, w, tw, cc, n = real_fused(
             sub, acc_loss, p, b, bs, layout, loss_weights=loss_weights)
-        return jax.lax.psum(lv, "data"), aux, sq, grads, w, tw, cc
+        return jax.lax.psum(lv, "data"), aux, sq, grads, w, tw, cc, n
 
     monkeypatch.setattr(plan_mod, "run_fused", psum_lv)
     rep = col.analyze_trace(_dp_trace(mesh=_one_device_mesh()))
