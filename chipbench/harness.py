@@ -19,15 +19,22 @@ kind or metric is a file of its own, found by the name in
 
 A reader's ``run`` holds ``cell``, ``config``, ``mix``, ``chips``,
 ``device_kind``, ``setup_s``, ``window_s`` (host clock), ``steps``,
-``tokens``, ``failed``, ``memory_peak_bytes``, ``flops_per_token`` and,
-with ``--trace 1``, ``trace`` (``trace.reduce_profile``; else None).
+``tokens``, ``failed``, ``memory_peak_bytes``, ``flops_per_token``,
+``counters`` (each of the program's counters that the job keeps, as the
+window's last step read it) and, with ``--trace 1``, ``trace``
+(``trace.reduce_profile``) and ``phases`` (``phases.split``: self time
+by phase scope and by program, idle by innermost host span, seconds a
+step; its ``phases`` is None for a job that gives no step HLO); else
+both None.
 
 A run: set-up (weights from the seed on the device, the job's first
 steps, which compile), then ``--seconds`` of steps, then the peak
 device memory, then — with the program's state freed — the reference,
 which decides ``correct``. With ``--trace 1`` the window runs under the
 profiler and the cell's per-layer metrics are read from the trace;
-with ``--trace 0`` its end-to-end metrics are read.
+after the peak is read, the job compiles its step again for its HLO
+text (``step_hlo``; from the process's own cache), which the phase
+split reads. With ``--trace 0`` the end-to-end metrics are read.
 """
 from __future__ import annotations
 
@@ -277,11 +284,14 @@ def run(argv=None, *, bench: Optional[Bench] = None,
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
 
-    summary = None
+    summary = split = None
     if trace_dir:
+        from chipbench import phases
         from chipbench.trace import reduce_profile
-        summary = reduce_profile(find_xplane(trace_dir),
-                                 [d.id for d in devices])
+        hlo = job.step_hlo() if hasattr(job, "step_hlo") else None
+        xplane, ids = find_xplane(trace_dir), [d.id for d in devices]
+        summary = reduce_profile(xplane, ids)
+        split = phases.split(xplane, ids, step_hlo=hlo)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     from chipbench import flops
@@ -295,7 +305,8 @@ def run(argv=None, *, bench: Optional[Bench] = None,
         "memory_peak_bytes": peak,
         "flops_per_token": flops.per_token(ctx.config, ctx.mix["seq"],
                                            job.weight_grads),
-        "trace": summary,
+        "counters": records[-1].get("counters", {}) if records else {},
+        "trace": summary, "phases": split,
     }
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = {}

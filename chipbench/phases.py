@@ -29,11 +29,15 @@ averages the same way over devices):
     span that holds it (``other`` where no span overlaps);
   * ``idle_in_modules``: the idle time that lies inside a module
     event, by program: the device holds the program but runs none of
-    its operations (the rest of the idle time lies between programs).
+    its operations (the rest of the idle time lies between programs);
+  * ``collectives``: the step program's self time in its all-reduce,
+    all-gather and reduce-scatter instructions, by the instruction's
+    opcode in the step's HLO text (JAX names a ``psum`` ``psum.<n>``).
 
-All are seconds a window step (``run_step`` spans in the
-window). ``trace.reduce_profile``, which the harness runs, reads none
-of this yet; ``tools/phase_split.py`` runs a cell and prints it.
+All are seconds a window step (``run_step`` or ``engine_step``
+spans in the window). The harness runs ``split`` in a ``--trace 1`` run
+beside ``trace.reduce_profile`` for the per-layer readers;
+``tools/phase_split.py`` runs a cell and prints it.
 """
 from __future__ import annotations
 
@@ -140,6 +144,11 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLED = re.compile(r"\b(?:calls|body|condition|to_apply|"
                      r"branch_computations)=(\{[^}]*\}|%?[\w.\-]+)")
 _NAME = re.compile(r"%?([\w.\-]+)")
+#: an instruction's opcode: the first lower-case word before ``(`` that
+#: follows a space, past the result's type and layout
+_OPCODE = re.compile(r" = .*?\s([a-z][\w\-]*)\(")
+COLLECTIVE_OPS = re.compile(r"^(?:all-reduce|all-gather|reduce-scatter)"
+                            r"(?:-start|-done)?$")
 
 
 def innermost_scope(op_name: str) -> str:
@@ -189,14 +198,27 @@ def step_phases(hlo_text: str) -> Dict[str, str]:
     return {name: phase(name) for name in own}
 
 
+def collective_instructions(hlo_text: str) -> set:
+    """Names of a compiled module's all-reduce, all-gather and
+    reduce-scatter instructions (and their async halves)."""
+    out = set()
+    for line in hlo_text.splitlines():
+        m, op = _INSTRUCTION.match(line), _OPCODE.search(line)
+        if m and op and COLLECTIVE_OPS.match(op.group(1)):
+            out.add(m.group(2))
+    return out
+
+
 def device_split(ops: Sequence[Span], modules: Sequence[Span], lo: float,
                  hi: float, spans: Sequence[Span],
-                 phase_of: Optional[Dict[str, str]] = None) -> dict:
+                 phase_of: Optional[Dict[str, str]] = None,
+                 collective: Optional[set] = None) -> dict:
     """One device's busy time, self time by module and (with
-    ``phase_of``, from ``step_phases``) the step module's by phase,
-    idle gaps by host span and by the module they lie in, and the
-    longest gap, within [lo, hi], in nanoseconds. ``ops`` are named as
-    ``trace.op_name`` names them."""
+    ``phase_of``, from ``step_phases``) the step module's by phase and
+    (with ``collective``, from ``collective_instructions``) in its
+    collectives, idle gaps by host span and by the module they lie in,
+    and the longest gap, within [lo, hi], in nanoseconds. ``ops`` are
+    named as ``trace.op_name`` names them."""
     ops = [(n, max(s, lo), min(e, hi)) for n, s, e in ops
            if min(e, hi) > max(s, lo)]
     busy = trace.union((s, e) for _, s, e in ops)
@@ -224,6 +246,11 @@ def device_split(ops: Sequence[Span], modules: Sequence[Span], lo: float,
         out["phases_ns"] = trace.self_times(
             [(phase_of.get(n.split(" ", 1)[0], UNMATCHED), s, e)
              for m, (n, s, e) in zip(mods, ops) if m == STEP_MODULE])
+    if collective is not None:
+        out["collectives_ns"] = trace.self_times(
+            [("collective" if n.split(" ", 1)[0] in collective else "", s, e)
+             for m, (n, s, e) in zip(mods, ops) if m == STEP_MODULE]
+        ).get("collective", 0.0)
     return out
 
 
@@ -263,9 +290,9 @@ def split(path: str, device_ids: Sequence[int],
     """``window_s``, ``busy_s``, ``steps``; in seconds a window step
     averaged over the devices, ``modules``, ``idle``,
     ``idle_in_modules`` and (given the step's HLO text; else None)
-    ``phases``; and the longest idle gap of any device (seconds, the
-    span it is charged to, the program whose module event holds its
-    start or ``?``)."""
+    ``phases`` and ``collectives``; and the longest idle gap of any
+    device (seconds, the span it is charged to, the program whose
+    module event holds its start or ``?``)."""
     spans, ops, modules = read_profile(path, device_ids)
     windows = [s for s in spans if s[0] == trace.WINDOW_SPAN]
     if len(windows) != 1:
@@ -277,12 +304,14 @@ def split(path: str, device_ids: Sequence[int],
     _, lo, hi = windows[0]
     inner = [s for s in spans if s[0] != trace.WINDOW_SPAN
              and s[1] >= lo and s[2] <= hi]
-    steps = sum(1 for s in inner if s[0] == "run_step")
+    steps = sum(1 for s in inner if s[0] in trace.STEP_SPANS)
     if not steps:
-        raise ValueError(f"{path}: no 'run_step' span in the window")
+        raise ValueError(f"{path}: no step span {trace.STEP_SPANS} in the "
+                         f"window")
     phase_of = step_phases(step_hlo) if step_hlo else None
-    per = [device_split(ops[i], modules[i], lo, hi, inner, phase_of)
-           for i in device_ids]
+    collective = collective_instructions(step_hlo) if step_hlo else None
+    per = [device_split(ops[i], modules[i], lo, hi, inner, phase_of,
+                        collective) for i in device_ids]
     scale = 1e-9 / len(per) / steps
 
     def pool(key):
@@ -300,7 +329,9 @@ def split(path: str, device_ids: Sequence[int],
             "idle_in_modules": {k: v for k, v in pool(
                 "idle_in_modules_ns").items() if v > 0},
             "longest_gap": {"s": ns * 1e-9, "span": span, "module": module},
-            "phases": pool("phases_ns") if phase_of is not None else None}
+            "phases": pool("phases_ns") if phase_of is not None else None,
+            "collectives": sum(d["collectives_ns"] for d in per) * scale
+            if collective is not None else None}
 
 
 def coverage(s: dict) -> dict:
@@ -324,3 +355,17 @@ def coverage(s: dict) -> dict:
         "trainer_spans_over_run_step_idle": named / in_step
         if in_step else None,
     }
+
+
+# ---------------------------------------------------------------------------
+# what the per-layer readers take from a run's split
+# ---------------------------------------------------------------------------
+
+def phase_ms(run: dict, scope: str) -> Optional[float]:
+    """The step program's self time under phase ``scope``, in ms a
+    step, from a traced run's split; None where the run has no phase
+    split or the scope ran nothing."""
+    split = run.get("phases")
+    seconds = (split.get("phases") or {}).get(scope) if split else None
+    return 1e3 * seconds if seconds else None
+

@@ -23,7 +23,8 @@ The noise ``ξ`` of one step is drawn from a key the caller gives, one
 key a leaf, split from it in the order of ``leaf_order``.
 
 Examples are taken one at a time (``lax.fori_loop``) so that only one
-per-example gradient is live.
+per-example gradient is live; on several devices (``over_devices``)
+each takes its own rows so, and the sums are added.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 F32 = jnp.float32
 
@@ -79,17 +81,12 @@ def noise_keys(key, leaf_order: Sequence[str]) -> dict:
     return dict(zip(leaf_order, jax.random.split(key, len(leaf_order))))
 
 
-def train_step(example_loss: Callable, params: dict, mu: dict, nu: dict,
-               t: int, ids, labels, key, row_weights, *, mode: str,
-               opt: AdamW, clip_norm: Optional[float], noise_std: float,
-               leaf_order: Sequence[str], clip: bool = True):
-    """One step. Returns (params, mu, nu, losses (B,), sq_norms (B,),
-    per-leaf norms of the gradient as AdamW's moments receive it, the
-    global-norm clip's factor).
-
-    ``row_weights`` (B,) weighs each example's clipped gradient in the
-    sum; it is all ones, and ``clip`` is True, except where a test
-    plants a fault."""
+def clipped_sum(example_loss: Callable, params: dict, ids, labels,
+                row_weights, *, mode: str, clip_norm: Optional[float],
+                clip: bool = True):
+    """(``Σ_j w_j c_j g_j`` in float32, losses (B,), squared norms (B,)),
+    one example at a time; ``c_j`` is the clip coefficient, 1 where
+    ``clip_norm`` is None or ``clip`` is False."""
     b = ids.shape[0]
     g0 = {k: jnp.zeros(v.shape, F32) for k, v in params.items()}
 
@@ -102,8 +99,44 @@ def train_step(example_loss: Callable, params: dict, mu: dict, nu: dict,
         acc = {k: acc[k] + row_weights[j] * c * g[k] for k in acc}
         return acc, losses.at[j].set(loss), sq.at[j].set(n)
 
-    grads, losses, sq = jax.lax.fori_loop(
+    return jax.lax.fori_loop(
         0, b, one, (g0, jnp.zeros(b, F32), jnp.zeros(b, F32)))
+
+
+def over_devices(mesh, axis: str = "data") -> Callable:
+    """``clipped_sum`` with the examples split over ``mesh``'s
+    ``axis``: each device takes its own rows one at a time, and the
+    devices' sums are added."""
+    def run(example_loss, params, ids, labels, row_weights, **kw):
+        def local(p, i, lab, w):
+            g, losses, sq = clipped_sum(example_loss, p, i, lab, w, **kw)
+            return jax.lax.psum(g, axis), losses, sq
+
+        rows = P(axis)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P(), rows, rows, rows),
+                             out_specs=(P(), rows, rows), check_vma=False)(
+            params, ids, labels, row_weights)
+
+    return run
+
+
+def train_step(example_loss: Callable, params: dict, mu: dict, nu: dict,
+               t: int, ids, labels, key, row_weights, *, mode: str,
+               opt: AdamW, clip_norm: Optional[float], noise_std: float,
+               leaf_order: Sequence[str], clip: bool = True,
+               summed: Callable = clipped_sum):
+    """One step. Returns (params, mu, nu, losses (B,), sq_norms (B,),
+    per-leaf norms of the gradient as AdamW's moments receive it, the
+    global-norm clip's factor).
+
+    ``row_weights`` (B,) weighs each example's clipped gradient in the
+    sum; it is all ones, and ``clip`` is True, except where a test
+    plants a fault. ``summed`` computes the sum (``clipped_sum``, or
+    ``over_devices``' form of it)."""
+    grads, losses, sq = summed(example_loss, params, ids, labels,
+                               row_weights, mode=mode, clip_norm=clip_norm,
+                               clip=clip)
     dtype = {k: p.dtype for k, p in params.items()}
     grads = {k: v.astype(dtype[k]) for k, v in grads.items()}
     if noise_std:

@@ -30,13 +30,17 @@ def _args(cell, seed, seconds):
             str(seconds), "--trace", "0"]
 
 
-def run_tiny(tmp_path, cell, fault="none", seed=4000000007, seconds=0.5):
-    """One run of ``cell`` at test sizes in a fresh process, with
-    ``fault`` planted; returns (exit code, result line or None, stderr)."""
+def run_tiny(tmp_path, cell, fault="none", seed=4000000007, seconds=0.5,
+             devices=1):
+    """One run of ``cell`` at test sizes in a fresh process on
+    ``devices`` CPU devices, with ``fault`` planted; returns (exit code,
+    result line or None, stderr)."""
     dest = tmp_path / f"bench-{cell}-{fault}"
+    env = _env(tmp_path)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     return _result(subprocess.run(
         [sys.executable, "-m", "chipbench.tests.tiny", str(dest), fault]
-        + _args(cell, seed, seconds), cwd=REPO, env=_env(tmp_path),
+        + _args(cell, seed, seconds), cwd=REPO, env=env,
         capture_output=True, text=True, timeout=600))
 
 

@@ -7,8 +7,8 @@ by host action (``phases.py``).
 
 In one process: the cell's set-up, as a run makes it; a window of
 ``--seconds`` with the profiler off, then one as long under it; then
-the compiled step's HLO text (``Trainer.lower_step``, compiled again:
-the process's own cache holds the step) and the split of the traced
+the compiled step's HLO text (the job's ``step_hlo``: ``Trainer.lower_step``
+compiled again, from the process's own cache) and the split of the traced
 window. Prints one JSON line: the two windows' tokens per
 second (what tracing costs), the split in ms a step, and
 ``phases.coverage``; with ``--out``, also writes it to
@@ -77,11 +77,7 @@ def main(argv=None) -> int:
         rates[name] = sum(r["tokens"] for r in records) / seconds
 
     t0, h0 = time.perf_counter(), hits[0]
-    lower = getattr(job.trainer, "lower_step", None)
-    hlo = None
-    if lower is not None:
-        batch = jax.device_put(ctx.stream.batch_at(0), devices[0])
-        hlo = lower(batch).compile().as_text()
+    hlo = job.step_hlo() if hasattr(job, "step_hlo") else None
     hlo_s = time.perf_counter() - t0
     split = phases.split(find_xplane(trace_dir), [d.id for d in devices],
                          step_hlo=hlo)

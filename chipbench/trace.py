@@ -34,6 +34,8 @@ Interval = Tuple[float, float]
 
 WINDOW_SPAN = "window"
 HOST_SPANS = ("loader", "run_step", "engine_step", "host_fetch")
+#: the spans of which the window holds one a step
+STEP_SPANS = ("run_step", "engine_step")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 ALLREDUCE = re.compile(r"all-reduce", re.IGNORECASE)
@@ -240,7 +242,7 @@ def reduce_profile(path: str, device_ids: Sequence[int]) -> dict:
     _, lo, hi = windows[0]
     inner = [s for s in spans if s[0] != WINDOW_SPAN and s[1] >= lo
              and s[2] <= hi]
-    n_steps = sum(1 for s in inner if s[0] in ("run_step", "engine_step"))
+    n_steps = sum(1 for s in inner if s[0] in STEP_SPANS)
     per_device = [device_numbers(devices[i], lo, hi, inner)
                   for i in device_ids]
     return summarize(per_device, lo, hi, n_steps)

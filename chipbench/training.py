@@ -1,5 +1,6 @@
 """Training jobs: the program's ``Trainer.run_step``, one batch a step,
-on one chip.
+on the cell's chips: one, or a data-parallel mesh over all of them (axis
+``"data"``), the global batch split over it.
 
 Set-up builds one trainer from weights drawn on the device from the
 seed, and drives it through its first ``CHECKED_STEPS`` steps with the
@@ -13,7 +14,9 @@ steps. The same trainer then runs the window.
 
 The reference runs the same steps in float32 (``reference/steps.py``),
 with the DP-SGD noise that the program draws: the same ``jax.random``
-calls on the trainer's key schedule (``noise_key``). Numbers, each a
+calls on the trainer's key schedule (``noise_key``), once after the sum
+on a mesh as on one chip. On a mesh each chip takes its own rows of the
+global batch one at a time, and the sums are added. Numbers, each a
 relative gap (0 is exact):
 
   loss    first step: |loss − ref| / |ref|
@@ -23,6 +26,10 @@ relative gap (0 is exact):
           ‖g_ref − noise‖, the clipped sum that the gradient carries
           beside its noise. The part of the gap along the noise, a
           scale that the global-norm clip sets, is left out of both.
+  quiet_sum  ``sum`` over the elements whose noise is at most
+          ``QUIET`` σC. Elsewhere a bfloat16 gradient rounds the
+          clipped sum, some 1e-4 of the noise an element, away: the
+          noise's own rounding step is larger than it.
   grad    worst leaf of the first gradient's norm:
           |‖g‖ − ‖g_ref‖| / max(‖g_ref‖, median leaf's)
   update  the same for the parameters' change over the checked steps
@@ -41,12 +48,17 @@ import gc
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from chipbench import program
 from chipbench.reference import common as C
 from chipbench.reference import steps as R
 
 CHECKED_STEPS = 3
+#: ``quiet_sum`` takes the elements whose noise is at most this share of
+#: σC: there a bfloat16 rounding step is under a tenth of the clipped
+#: sum that qwen2-7b-2l's rows give an element
+QUIET = 2.0 ** -8
 
 
 def noise_key(seed: int, t: int):
@@ -78,13 +90,19 @@ def _host(d: dict) -> dict:
 def _leaf_sums(mu, mu_ref, key, coef, *, dtype, std):
     """⟨d, d⟩, ⟨d, n⟩, ⟨n, n⟩, ⟨t, t⟩, ⟨t, n⟩ of one leaf, with d the
     gap of the two first moments, n the reference's noise as it enters
-    its first moment, and t the rest of the reference's moment."""
+    its first moment, and t the rest of the reference's moment; then
+    the same five over the elements whose noise is quiet (``QUIET``)."""
     f32 = jnp.float32
-    n = coef * R.noise(key, mu_ref.shape, dtype, std).astype(f32) if std \
+    raw = R.noise(key, mu_ref.shape, dtype, std).astype(f32) if std \
         else jnp.zeros_like(mu_ref)
+    n = coef * raw
     d, t = mu.astype(f32) - mu_ref, mu_ref - n
+    quiet = (jnp.abs(raw) <= QUIET * std).astype(f32)
     return jnp.stack([jnp.sum(d * d), jnp.sum(d * n), jnp.sum(n * n),
-                      jnp.sum(t * t), jnp.sum(t * n)])
+                      jnp.sum(t * t), jnp.sum(t * n),
+                      jnp.sum(quiet * d * d), jnp.sum(quiet * d * n),
+                      jnp.sum(quiet * n * n), jnp.sum(quiet * t * t),
+                      jnp.sum(quiet * t * n)])
 
 
 def rel(a, b) -> float:
@@ -103,26 +121,37 @@ class TrainJob:
     #: faults the calibration plants in the reference put in the
     #: program's place: half the batch dropped (the sum over the rest
     #: doubled); the clipped sum taken over half the rows, doubled,
-    #: with the whole batch's losses and norms; the clip left out
+    #: with the whole batch's losses and norms; the clip left out; on a
+    #: mesh, the clipped sum of one shard's rows times the shard count,
+    #: what a missing psum gives (``one_shard``)
     FAULTS = ("half_batch", "half_grad", "unclipped")
+    #: the numeric counters of ``run_step``'s metrics that a step keeps
+    COUNTERS = ("pex_backwards",)
 
     def __init__(self, ctx):
-        if ctx.chips != 1:
-            raise ValueError(f"a training job runs on one chip, the cell "
-                             f"asks for {ctx.chips}")
         self.ctx = ctx
         self.args = ctx.args
         self.cfg = ctx.config
-        self.init = jax.jit(functools.partial(ctx.family.init_params,
-                                              self.cfg))
+        init = functools.partial(ctx.family.init_params, self.cfg)
+        if ctx.chips == 1:
+            self.mesh, self.rows = None, ctx.devices[0]
+            self.init = jax.jit(init)
+        else:
+            if ctx.mix["batch"] % ctx.chips:
+                raise ValueError(f"batch {ctx.mix['batch']} does not split "
+                                 f"over {ctx.chips} chips")
+            self.mesh = Mesh(np.array(ctx.devices), ("data",))
+            self.rows = NamedSharding(self.mesh, P("data"))
+            self.init = jax.jit(init, out_shardings=NamedSharding(
+                self.mesh, P()))
         self.trainer = None
         self.checked = {"loss": [], "norm_mean": [], "norm_max": []}
 
     def faults(self) -> tuple:
         """The faults this cell's job can have."""
-        if self.args.get("clip_norm") is None:
-            return ("half_batch",)
-        return self.FAULTS
+        own = self.FAULTS if self.args.get("clip_norm") is not None \
+            else ("half_batch",)
+        return own + (("one_shard",) if self.mesh is not None else ())
 
     # -- the program ---------------------------------------------------
     def setup(self):
@@ -130,7 +159,8 @@ class TrainJob:
         mix = self.ctx.mix
         self.trainer = program.trainer(self.cfg, params, self.args,
                                        seed=self.ctx.seed31,
-                                       batch=mix["batch"], seq=mix["seq"])
+                                       batch=mix["batch"], seq=mix["seq"],
+                                       mesh=self.mesh)
         del params
         for i in range(CHECKED_STEPS):
             rec = self.step(i)
@@ -150,17 +180,23 @@ class TrainJob:
 
     def step(self, i: int) -> dict:
         with jax.profiler.TraceAnnotation("loader"):
-            batch = jax.device_put(self.ctx.stream.batch_at(i),
-                                   self.ctx.devices[0])
+            batch = jax.device_put(self.ctx.stream.batch_at(i), self.rows)
         with jax.profiler.TraceAnnotation("run_step"):
             m = self.trainer.run_step(batch)
             self.trainer.step += 1
         rec = {"tokens": self.ctx.stream.tokens_per_step,
                "failed": bool(m.get("skipped") or m.get("quarantined")),
-               "loss": m["loss"]}
+               "loss": m["loss"],
+               "counters": {k: m[k] for k in self.COUNTERS if k in m}}
         if "norm_mean" in m:
             rec["norm_mean"], rec["norm_max"] = m["norm_mean"], m["norm_max"]
         return rec
+
+    def step_hlo(self) -> str:
+        """The HLO text of the step program the window ran, compiled
+        again (the process's own cache holds it)."""
+        batch = jax.device_put(self.ctx.stream.batch_at(0), self.rows)
+        return self.trainer.lower_step(batch).compile().as_text()
 
     def answers(self) -> dict:
         return {k: v for k, v in self.checked.items() if v}
@@ -185,6 +221,10 @@ class TrainJob:
                                ).astype(np.float32)
         elif fault == "unclipped":
             clip = False
+        elif fault == "one_shard" and self.mesh is not None:
+            n = self.ctx.chips
+            weights = np.where(np.arange(b) < b // n, float(n), 0.0
+                               ).astype(np.float32)
         elif fault is not None:
             raise ValueError(f"unknown fault {fault!r}")
         opt = R.AdamW(**{k: args[k] for k in program.ADAMW_KEYS})
@@ -194,7 +234,8 @@ class TrainJob:
             R.train_step, loss, mode=mode, opt=opt,
             clip_norm=args.get("clip_norm"),
             noise_std=args.get("noise_std", 0.0), leaf_order=order,
-            clip=clip), donate_argnums=(0, 1, 2))
+            clip=clip, summed=R.clipped_sum if self.mesh is None
+            else R.over_devices(self.mesh)), donate_argnums=(0, 1, 2))
         out = {"loss": [], "norm_mean": [], "norm_max": [], "order": order,
                "dtypes": {k: v.dtype for k, v in p.items()}}
         mu = {k: jnp.zeros(v.shape, jnp.float32) for k, v in p.items()}
@@ -221,9 +262,9 @@ class TrainJob:
         return out
 
     def _sum_gaps(self, mu: dict, ref: dict) -> tuple:
-        """``sum`` over the whole gradient, and its worst leaf among
-        those that carry a thousandth of the median leaf's sum or
-        more."""
+        """``sum`` over the whole gradient, its worst leaf among those
+        that carry a thousandth of the median leaf's sum or more, and
+        ``quiet_sum``."""
         args = self.args
         std = args.get("noise_std", 0.0) * (args.get("clip_norm") or 0.0)
         coef = (1 - args["b1"]) * ref["gscale"]
@@ -232,20 +273,22 @@ class TrainJob:
             jnp.asarray(mu[k]), jnp.asarray(r), keys[k], coef,
             dtype=ref["dtypes"][k], std=std), np.float64)
             for k, r in ref["mu1"].items()}
-        dd, dn, nn, tt, tn = sum(per.values())
-        a, c = (dn / nn, tn / nn) if nn > 0 else (0.0, 0.0)
+        total = sum(per.values())
 
-        def gap(s):
+        def gap(s, whole):
+            dn, nn, tn = whole[1], whole[2], whole[4]
+            a, c = (dn / nn, tn / nn) if nn > 0 else (0.0, 0.0)
             d = s[0] - 2 * a * s[1] + a * a * s[2]
             t = s[3] - 2 * c * s[4] + c * c * s[2]
             return max(d, 0.0), max(t, 0.0)
 
-        d, t = gap((dd, dn, nn, tt, tn))
-        leaves = {k: gap(s) for k, s in per.items()}
+        d, t = gap(total[:5], total[:5])
+        leaves = {k: gap(s[:5], total[:5]) for k, s in per.items()}
         med = float(np.median([t for _, t in leaves.values()]))
         worst = max(np.sqrt(d / t) for d, t in leaves.values()
                     if t >= 1e-6 * med and t > 0)
-        return float(np.sqrt(d / t)), float(worst)
+        qd, qt = gap(total[5:], total[5:])
+        return float(np.sqrt(d / t)), float(worst), float(np.sqrt(qd / qt))
 
     def numbers(self, answers: dict, ref: dict) -> dict:
         med = float(np.median(list(ref["grad"].values())))
@@ -256,7 +299,8 @@ class TrainJob:
         out = {"loss": gaps[0][0]}
         if len(keys) > 1:
             out["norms"] = max(gaps[0][1:])
-        out["sum"], out["sum_leaf"] = self._sum_gaps(answers["mu1"], ref)
+        out["sum"], out["sum_leaf"], out["quiet_sum"] = self._sum_gaps(
+            answers["mu1"], ref)
         out["grad"] = worst_leaf(answers["grad"], ref["grad"])
         out["update"] = worst_leaf(answers["update"], ref["update"], moved)
         out["later"] = max(max(g) for g in gaps[1:])
